@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import quantize as qz, quest, retrieval as rt
+from repro.launch.compile_cache import enable_compilation_cache
 
 from .common import emit, train_tiny_lm
 from .bench_recall import _recall, model_keys
@@ -64,6 +65,7 @@ def run():
 
 
 def main():
+    enable_compilation_cache()
     run()
 
 

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.quantize import packed_nbytes
+from repro.launch.compile_cache import enable_compilation_cache
 
 from .common import (
     bench_model_cfg, emit, emit_paged_score_traffic, emit_score_traffic,
@@ -172,6 +173,7 @@ def smoke(out_dir: str = "."):
 
 
 def main():
+    enable_compilation_cache()
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--smoke", action="store_true",
                     help="CI gate: assert zero score-tensor bytes on the "

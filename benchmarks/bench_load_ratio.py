@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import quantize as qz, quest
+from repro.launch.compile_cache import enable_compilation_cache
 
 from .common import bench_model_cfg, emit, emit_paged_score_traffic, emit_score_traffic
 from .flopcount import count_fn_gather_bytes
@@ -151,6 +152,7 @@ def pool_utilization():
 
 
 def main():
+    enable_compilation_cache()
     run()
     pool_utilization()
 

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .common import emit, policy_bundle, train_tiny_lm
+from repro.launch.compile_cache import enable_compilation_cache
 
 SEQ = 256
 N_FACTS = 3
@@ -116,6 +117,7 @@ def run():
 
 
 def main():
+    enable_compilation_cache()
     run()
 
 

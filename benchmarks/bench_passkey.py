@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data.passkey import N_DIGITS, make_passkey_batch
+from repro.launch.compile_cache import enable_compilation_cache
 
 from .common import emit, policy_bundle, train_tiny_lm
 
@@ -52,6 +53,7 @@ def run():
 
 
 def main():
+    enable_compilation_cache()
     run()
 
 

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data.pipeline import lm_tokens
+from repro.launch.compile_cache import enable_compilation_cache
 
 from .common import emit, policy_bundle, train_tiny_lm
 
@@ -55,6 +56,7 @@ def run():
 
 
 def main():
+    enable_compilation_cache()
     run()
 
 

@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.core import quantize as qz
 from repro.core import quest, retrieval as rt
+from repro.launch.compile_cache import enable_compilation_cache
 
 from .common import emit, timeit, train_tiny_lm
 
@@ -100,6 +101,7 @@ def run(budget_k: int = 64) -> list[str]:
 
 
 def main():
+    enable_compilation_cache()
     run()
 
 

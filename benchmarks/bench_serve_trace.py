@@ -63,6 +63,8 @@ import numpy as np
 
 from repro.configs import reduced_config
 from repro.core.policy import PolicyConfig
+from repro.launch.compile_cache import enable_compilation_cache
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.obs.tracing import PID_REQUEST, derive_serving_metrics
 from repro.serving import (
@@ -191,9 +193,9 @@ def device_mesh(devices: int):
     if devices == 1:
         return None
     if devices == 2:
-        return jax.make_mesh((2,), ("data",))
+        return make_mesh((2,), ("data",))
     if devices == 4:
-        return jax.make_mesh((2, 2), ("data", "model"))
+        return make_mesh((2, 2), ("data", "model"))
     raise SystemExit(f"--devices must be 1, 2 or 4, got {devices}")
 
 
@@ -619,6 +621,7 @@ def full(*, seed: int = 0, chunk_tokens: int = 256,
 
 
 def main():
+    enable_compilation_cache()
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--smoke", action="store_true",
                     help="CI gate: bursty trace, chunked vs monolithic, "

@@ -37,7 +37,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def _run_dryrun(args: list[str], timeout: int = 3600) -> dict:
     cmd = [sys.executable, "-m", "repro.launch.dryrun", "--json"] + args
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # a CPU tool: the children compile against 512 host placeholder
+    # devices and must never take an accelerator the host has
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     r = subprocess.run(cmd, capture_output=True, text=True, timeout=timeout,
                        env=env, cwd=REPO)
     if r.returncode != 0:
@@ -186,6 +189,7 @@ def analyse_cell(arch: str, shape: str, *, policy: str = "fier",
 
 
 def main() -> int:
+    os.environ["JAX_PLATFORMS"] = "cpu"   # this process stays off the chip too
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

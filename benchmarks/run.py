@@ -18,6 +18,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compilation_cache
+
 MODULES = [
     "bench_load_ratio",
     "bench_recall",
@@ -30,6 +32,7 @@ MODULES = [
 
 
 def main() -> None:
+    enable_compilation_cache()
     print("name,us_per_call,derived")
     failures = []
     for name in MODULES:

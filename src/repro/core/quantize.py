@@ -113,13 +113,30 @@ def quantize(K: jax.Array, group: int = 32) -> QuantizedKeys:
     return QuantizedKeys(pack_bits(bits), scale, zero, group)
 
 
+def dequant_levels(scale: jax.Array, zero: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """The two per-(group, channel) key levels ``(z + s, z − s)`` in bf16.
+
+    Each level is one f32 add of two bf16 values, rounded once to bf16 —
+    an expression every backend evaluates identically.  This is the
+    score contract shared by the Pallas scorer
+    (``kernels.fier_score.score_block``) and the jnp oracle
+    (``core.retrieval.approx_scores``): a token's dequantized key is the
+    level its code bit selects, and its score is the bf16 dot of the
+    bf16 query with it, accumulated in f32."""
+    s = scale.astype(jnp.float32)
+    z = zero.astype(jnp.float32)
+    return (z + s).astype(jnp.bfloat16), (z - s).astype(jnp.bfloat16)
+
+
 def dequantize(q: QuantizedKeys) -> jax.Array:
-    """K̃ = code·s + z ∈ {z−s, z+s}.  Returns bf16 [B, S, H, D]."""
+    """K̃ ∈ {z−s, z+s}, the level each code bit selects.  bf16 [B, S, H, D]."""
+    hi, lo = dequant_levels(q.scale, q.zero)
     bits = unpack_bits(q.codes)
-    pm1 = bits.astype(jnp.bfloat16) * 2.0 - 1.0
-    s = jnp.repeat(q.scale, q.group, axis=1)
-    z = jnp.repeat(q.zero, q.group, axis=1)
-    return pm1 * s + z
+    return jnp.where(
+        bits == 1,
+        jnp.repeat(hi, q.group, axis=1),
+        jnp.repeat(lo, q.group, axis=1),
+    )
 
 
 def packed_nbytes(S: int, H: int, D: int, group: int) -> int:

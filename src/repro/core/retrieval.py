@@ -26,10 +26,11 @@ APPROX_SCORE_BLOCK = 2048  # seq tokens per scan step (≈ a VMEM block)
 def approx_scores(q: jax.Array, qk: QuantizedKeys) -> jax.Array:
     """s̃ = q·K̃ᵀ from packed 1-bit codes.  Returns f32 [B, Hq, S].
 
-    Efficient form (what the Pallas kernel implements): for token i in
-    seq-group G(i),
-        s̃_i = (q ⊙ s_G)·codes_i + q·z_G
-    i.e. one group-rescaled query per group plus a per-group constant.
+    For token i in seq-group G(i) the dequantized key is the level its
+    code bit selects, k̃_i ∈ {z_G + s_G, z_G − s_G} rounded to bf16
+    (``quantize.dequant_levels``), and s̃_i = bf16(q)·k̃_i accumulated in
+    f32 — the expression the Pallas scorer evaluates
+    (``kernels.fier_score.score_block``; DESIGN.md §One-pass).
 
     Computed *blockwise* over the sequence (lax.scan): the f32 unpack of
     the codes lives one block at a time, mirroring the kernel's
@@ -60,35 +61,27 @@ def approx_scores(q: jax.Array, qk: QuantizedKeys) -> jax.Array:
 
 
 def _approx_scores_block(q, codes, scale, zero, g) -> jax.Array:
-    """bf16-valued operands, f32 arithmetic — the exact MXU contract of the
-    Pallas kernel (bf16 inputs, every product exact in f32, f32 accumulate).
-
-    The operands are *rounded to bf16 values* but the arithmetic runs in
-    f32: a bf16×bf16 product fits f32 exactly, so the only rounding left
-    is the f32 accumulation — which makes this block function bit-stable
-    whether it runs eagerly, jitted, or as a ``lax.scan`` body (the old
-    version multiplied q⊙s *in bf16*, and XLA kept the fused intermediate
-    in f32 under scan but rounded it eagerly, so results depended on
-    APPROX_SCORE_BLOCK; caught by
-    tests/test_retrieval.py::test_approx_scores_blockwise_independent_of_block)."""
-    from .quantize import unpack_bits
+    """One block of the score contract: select each token's bf16 key level
+    by its code bit, then a bf16 dot with f32 accumulation.  The levels
+    round once (``dequant_levels``) and bf16×bf16 products are exact in
+    f32, so only the f32 accumulation rounds — the block function is
+    bit-stable eagerly, jitted, or as a ``lax.scan`` body
+    (tests/test_retrieval.py::test_approx_scores_blockwise_independent_of_block)."""
+    from .quantize import dequant_levels, unpack_bits
 
     B, Hq, D = q.shape
     S = codes.shape[1] * 8
     Hkv = codes.shape[2]
     rep = Hq // Hkv
-    bits = unpack_bits(codes).astype(jnp.float32)
-    pm1 = (bits * 2.0 - 1.0).reshape(B, S // g, g, Hkv, D)  # exact ±1
-    qf = q.astype(jnp.bfloat16).astype(jnp.float32).reshape(B, Hkv, rep, D)
-    qs = qf[:, None] * scale.astype(jnp.float32)[:, :, :, None, :]  # exact
-    const = jnp.einsum(
-        "bhrd,bghd->bghr", qf, zero.astype(jnp.float32),
+    hi, lo = dequant_levels(scale, zero)                   # [B, S/g, Hkv, D]
+    bits = unpack_bits(codes).reshape(B, S // g, g, Hkv, D)
+    keys = jnp.where(bits == 1, hi[:, :, None], lo[:, :, None])
+    qb = q.astype(jnp.bfloat16).reshape(B, Hkv, rep, D)
+    s = jnp.einsum(
+        "bhrd,bshd->bhrs", qb, keys.reshape(B, S, Hkv, D),
         preferred_element_type=jnp.float32,
     )
-    s = jnp.einsum(
-        "bghrd,bgthd->bghrt", qs, pm1, preferred_element_type=jnp.float32,
-    ) + const[..., None]
-    return s.transpose(0, 2, 3, 1, 4).reshape(B, Hq, S)
+    return s.reshape(B, Hq, S)
 
 
 def exact_scores(q: jax.Array, K: jax.Array) -> jax.Array:

@@ -3,12 +3,12 @@
 The paper's Triton kernel reads 1-bit quantized keys and computes
 approximate attention scores.  TPU adaptation (DESIGN.md §2): the win is
 HBM *bytes*, not popcount arithmetic — packed codes stream HBM→VMEM at
-1/16 the bf16 key bytes, unpack to ±1 inside VREGs, and the MXU computes
-the two small matmuls
+1/16 the bf16 key bytes, unpack inside VREGs, each bit selects one of
+its group's two key levels z ± s, and the MXU computes
 
-    s̃[t, r] = Σ_d (codes±1[t,d] · s[t,d]) · q[r,d]  +  Σ_d z[t,d] · q[r,d]
+    s̃[t, r] = Σ_d k̃[t,d] · q[r,d],   k̃[t,d] = (z + s if bit else z − s)[G(t), d]
 
-with the group-broadcast of (s, z) done in-register (scale/zero add
+with the group-broadcast of the levels done in-register (scale/zero add
 2·16/g bits per weight bit — Eq. 8's load ratio, measured exactly in
 bench_load_ratio).
 
@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.quantize import dequant_levels
+
 
 def score_block(
     qbf: jax.Array,
@@ -43,31 +45,30 @@ def score_block(
     qbf [rep, D] bf16; codes [blk_s/8, D] uint8; scale/zero [blk_s/g, D]
     → f32 [rep, blk_s].
 
-    bf16 operands, f32 MXU accumulation (±1 and the stored (s, z) are
-    exact in bf16).  Shared by the score-scan kernel and the one-pass
-    fused-retrieval kernel so their per-token scores are *bit-identical*
-    — the one-pass index set is validated exactly against
-    select-over-``fier_score``, which only holds if both paths evaluate
-    the same expression at the same shapes.
+    The score contract (``quantize.dequant_levels``; DESIGN.md §One-pass):
+    each token's key is the bf16 level ``z ± s`` its code bit selects, and
+    its score is one bf16 MXU dot with the query, accumulated in f32.  The
+    levels are formed once per (group, channel) — ``g``× fewer VPU ops than
+    dequantizing per token — and the jnp oracle
+    (``core.retrieval.approx_scores``) evaluates the same expression.
+    Shared by the score-scan kernel and the one-pass fused-retrieval
+    kernel, so their per-token scores are bit-identical.
     """
     n8, D = codes.shape
     blk_s = n8 * 8
-    # unpack: bit t of byte i is token 8i+t
-    shifts = jax.lax.broadcasted_iota(jnp.uint8, (n8, 8, D), 1)
-    bits = (codes[:, None, :] >> shifts) & jnp.uint8(1)
-    pm1 = bits.reshape(blk_s, D).astype(jnp.bfloat16) * 2.0 - 1.0
-
     ng = scale.shape[0]
-    scale_b = jnp.broadcast_to(
-        scale.astype(jnp.bfloat16)[:, None, :], (ng, group, D)
-    ).reshape(blk_s, D)
-    zero_b = jnp.broadcast_to(
-        zero.astype(jnp.bfloat16)[:, None, :], (ng, group, D)
-    ).reshape(blk_s, D)
+    # unpack in int32 (native (8, 128) tiles): bit t of byte i is token 8i+t
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (n8, 8, D), 1)
+    bits = (codes.astype(jnp.int32)[:, None, :] >> shifts) & 1
+    bits = bits.reshape(blk_s, D)
 
-    a = pm1 * scale_b + zero_b           # = dequantized keys, in-register
+    # select in f32 (bf16 levels are exact there), one exact cast back
+    hi, lo = (x.astype(jnp.float32) for x in dequant_levels(scale, zero))
+    hi_b = jnp.broadcast_to(hi[:, None, :], (ng, group, D)).reshape(blk_s, D)
+    lo_b = jnp.broadcast_to(lo[:, None, :], (ng, group, D)).reshape(blk_s, D)
+    keys = jnp.where(bits == 1, hi_b, lo_b).astype(jnp.bfloat16)
     return jax.lax.dot_general(
-        qbf, a, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        qbf, keys, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
 
 

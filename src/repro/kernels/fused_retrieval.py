@@ -44,10 +44,10 @@ VMEM per step: 2 double-buffer slots of (codes + scale + zero) block ≈
 2·(blk_s·D/8 + 2·(blk_s/g)·D·2) bytes — 48 KiB at blk_s = 512, D = 128,
 g = 32 — plus the [1, budget] index block.  Grid: (B·Hkv,).
 
-Interpret-mode notes (CPU CI runs the exact kernel code): the index
-compaction uses a bounded ``.at[].set(mode="drop")`` scatter on a
-VREG-resident [budget] vector (never a sort), and the histogram is a
-blockwise one-hot reduction — both stay on-chip on TPU.
+Mosaic notes (CPU CI interprets the exact kernel code): the histogram,
+the in-block prefix counts and the rank placement of the compaction are
+masked reductions over iota comparisons — no sort, cumsum or scatter,
+none of which Mosaic lowers (DESIGN.md §Chip layouts).
 """
 from __future__ import annotations
 
@@ -67,19 +67,52 @@ NPASS = 4    # radix-histogram passes: 8 bits of the uint32 keys per pass
 RADIX = 256  # buckets per pass
 
 
+def _iota(shape, dim):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def _to_col(x: jax.Array) -> jax.Array:
+    """Exact [1, n] → [n, 1] int32 transpose as a masked lane reduction
+    (each output sums one value and n − 1 zeros).  Mosaic has no general
+    small-shape transpose; this is n² VPU work, trivial at block size."""
+    n = x.shape[1]
+    return jnp.sum(
+        jnp.where(_iota((n, n), 0) == _iota((n, n), 1), x, 0),
+        axis=1, keepdims=True,
+    )
+
+
+def head_rows(x: jax.Array, h) -> jax.Array:
+    """Rows of kv head ``h`` out of an all-heads tile: [r, Hkv, D] → [r, D]
+    (int32 for integer input, else f32), exact — each output sums one value
+    and Hkv − 1 zeros.
+
+    The paged pools keep Hkv as the second-minor (tiled) dim, and Mosaic
+    DMAs only whole tiles: a single head's rows cannot be sliced out of
+    HBM, so the kernels DMA every head of a row and pick ``h`` in VMEM."""
+    x = x.astype(jnp.int32 if jnp.issubdtype(x.dtype, jnp.integer) else jnp.float32)
+    sel = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) == h
+    return jnp.sum(jnp.where(sel, x, 0), axis=1)
+
+
 def _threshold_select(sweep, budget: int, idx_ref, tau_ref, m_ref):
     """Radix-histogram τ search + tie-aware index compaction over a
-    ``sweep(fold, init)`` abstraction that folds over (keys, pos) blocks.
+    ``sweep(fold, init)`` abstraction that folds over scored blocks
+    ``(keys_row [1, n], keys_col [n, 1], pos_col [n, 1])``.
 
     Shared verbatim by the contiguous (slab) and the page-table-aware
     retrieval kernels: both produce per-block monotone-uint32 keys of the
     masked kv scores; only the *addressing* of the code stream differs.
     Writes the selected index set, τ, and the strictly-greater count to
     the (lane-padded) output refs.
+
+    Written for Mosaic: no cumsum, reversal or scatter.  Prefix counts
+    and the rank scatter are masked reductions over iota comparisons, and
+    the loop carries are [1, 1] vectors.
     """
     # ---- phase 1: radix-histogram search for τ (the budget-th largest key)
     def radix_pass(p, carry):
-        t, remaining, greater = carry
+        t, remaining, greater = carry                       # [1, 1] each
         pw = p.astype(jnp.uint32)
         shift = jnp.uint32(24) - jnp.uint32(8) * pw
         # participation: keys matching the 8p prefix bits found so far
@@ -91,78 +124,114 @@ def _threshold_select(sweep, budget: int, idx_ref, tau_ref, m_ref):
             << jnp.minimum(jnp.uint32(32) - jnp.uint32(8) * pw, jnp.uint32(31)),
         )
 
-        def fold(keys, pos, hist):
-            blk = keys.shape[1]
-            part = (keys & himask) == t                     # [1, blk]
+        def fold(blk, hist):
+            _, keys, _ = blk                                # keys [n, 1]
+            n = keys.shape[0]
+            part = (keys & himask) == t
             digit = ((keys >> shift) & jnp.uint32(0xFF)).astype(jnp.int32)
-            onehot = (
-                digit[0][:, None]
-                == jax.lax.broadcasted_iota(jnp.int32, (blk, RADIX), 1)
-            ) & part[0][:, None]
-            return hist + onehot.astype(jnp.int32).sum(axis=0)[None, :]
+            onehot = (digit == _iota((n, RADIX), 1)) & part  # [n, RADIX]
+            return hist + jnp.sum(
+                onehot.astype(jnp.int32), axis=0, keepdims=True
+            )
 
-        hist = sweep(fold, jnp.zeros((1, RADIX), jnp.int32))
-        ge = jnp.cumsum(hist[:, ::-1], axis=1)[:, ::-1]     # count(digit ≥ j)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (1, RADIX), 1)
+        hist = sweep(fold, jnp.zeros((1, RADIX), jnp.int32))  # [1, RADIX]
+        # ge[j] = count(digit ≥ j), bucket j on sublanes
+        ge = jnp.sum(
+            jnp.where(
+                _iota((RADIX, RADIX), 1) >= _iota((RADIX, RADIX), 0), hist, 0
+            ),
+            axis=1, keepdims=True,
+        )                                                   # [RADIX, 1]
         # τ's digit: the highest bucket where the ≥-count reaches `remaining`
-        jstar = jnp.max(jnp.where(ge >= remaining, iota, -1))
-        above = jnp.sum(jnp.where(iota > jstar, hist, 0))
+        jstar = jnp.max(
+            jnp.where(ge >= remaining, _iota((RADIX, 1), 0), -1),
+            axis=0, keepdims=True,
+        )                                                   # [1, 1]
+        above = jnp.sum(
+            jnp.where(_iota((1, RADIX), 1) > jstar, hist, 0),
+            axis=1, keepdims=True,
+        )
         t = t | (jstar.astype(jnp.uint32) << shift)
         return t, remaining - above, greater + above
 
     tau_key, _, m = jax.lax.fori_loop(
         0, NPASS, radix_pass,
-        (jnp.uint32(0), jnp.int32(budget), jnp.int32(0)),
+        (
+            jnp.zeros((1, 1), jnp.uint32),
+            jnp.full((1, 1), budget, jnp.int32),
+            jnp.zeros((1, 1), jnp.int32),
+        ),
     )
     # m = |{ key > τ }| exactly: every strictly-greater key is counted at
     # the first radix pass where its digit exceeds τ's (it matches the
     # prefix up to that pass), and never again after it stops matching.
 
     # ---- phase 2: re-score and compact { key > τ } ∪ first (budget−m) ties
-    def compact_fold(keys, pos, carry):
+    def compact_fold(blk, carry):
         ngt, ntie, out = carry
-        gt = (keys > tau_key)[0]                            # [blk]
-        tie = (keys == tau_key)[0]
-        cgt = jnp.cumsum(gt.astype(jnp.int32))
-        ctie = jnp.cumsum(tie.astype(jnp.int32))
+        keys_row, keys_col, pos_col = blk
+        n = keys_row.shape[1]
+        gt_row = (keys_row > tau_key).astype(jnp.int32)     # [1, n]
+        tie_row = (keys_row == tau_key).astype(jnp.int32)
+        gt = keys_col > tau_key                             # [n, 1]
+        tie = keys_col == tau_key
+        # inclusive in-block prefix counts: row i sums tokens t ≤ i
+        upto = _iota((n, n), 1) <= _iota((n, n), 0)
+        cgt = jnp.sum(jnp.where(upto, gt_row, 0), axis=1, keepdims=True)
+        ctie = jnp.sum(jnp.where(upto, tie_row, 0), axis=1, keepdims=True)
         take_tie = tie & (ntie + ctie <= budget - m)
+        # rank of each selected token: >τ fill [0, m) in ascending
+        # position, taken ties fill [m, budget); −1 = not selected
         dest = jnp.where(
-            gt, ngt + cgt - 1,
-            jnp.where(take_tie, m + ntie + ctie - 1, budget),
+            gt, ngt + cgt - 1, jnp.where(take_tie, m + ntie + ctie - 1, -1)
+        )                                                   # [n, 1]
+        out = out + jnp.sum(
+            jnp.where(dest == _iota((n, budget), 1), pos_col, 0),
+            axis=0, keepdims=True,
+        )                                                   # [1, budget]
+        return (
+            ngt + jnp.sum(gt_row, axis=1, keepdims=True),
+            ntie + jnp.sum(tie_row, axis=1, keepdims=True),
+            out,
         )
-        # bounded scatter by rank: >τ fill [0, m) in ascending position,
-        # taken ties fill [m, budget); dest == budget is dropped
-        out = out.at[dest].set(pos[0], mode="drop")
-        return ngt + cgt[-1], ntie + ctie[-1], out
 
     _, _, out = sweep(
         compact_fold,
-        (jnp.int32(0), jnp.int32(0), jnp.zeros((budget,), jnp.int32)),
+        (
+            jnp.zeros((1, 1), jnp.int32),
+            jnp.zeros((1, 1), jnp.int32),
+            jnp.zeros((1, budget), jnp.int32),
+        ),
     )
     idx_ref[...] = out.reshape(idx_ref.shape)
-    tau_ref[...] = jnp.full(tau_ref.shape, _unsortable(tau_key), jnp.float32)
-    m_ref[...] = jnp.full(m_ref.shape, m, jnp.int32)
+    tau_ref[...] = jnp.broadcast_to(_unsortable(tau_key), tau_ref.shape)
+    m_ref[...] = jnp.broadcast_to(m, m_ref.shape)
 
 
 def _masked_block_keys(s, i, blk_s, length, sink, recent, group_reduce):
     """Group-reduce + mask one scored block and lift to monotone keys.
 
     s [rep, blk_s] f32 (VREG-resident scores) → (keys uint32 [1, blk_s],
-    pos int32 [1, blk_s]).  Shared by the slab and paged kernels so the
-    masking arithmetic is identical bit for bit.
+    the same keys as a column [blk_s, 1], positions int32 [blk_s, 1]).
+    Shared by the slab and paged kernels so the masking arithmetic is
+    identical bit for bit.
     """
     if group_reduce == "max":
         kv = s.max(axis=0, keepdims=True)                   # [1, blk_s]
     else:
         kv = s.sum(axis=0, keepdims=True)
-    pos = i * blk_s + jax.lax.broadcasted_iota(jnp.int32, (1, blk_s), 1)
+    pos = i * blk_s + _iota((1, blk_s), 1)
     kv = jnp.where(pos < length, kv, NEG_INF)
     if sink > 0:
         kv = jnp.where(pos < sink, jnp.inf, kv)
     if recent > 0:
         is_recent = (pos >= length - recent) & (pos < length)
         kv = jnp.where(is_recent, jnp.inf, kv)
-    return _sortable_keys(kv), pos
+    keys = _sortable_keys(kv)
+    keys_col = jax.lax.bitcast_convert_type(
+        _to_col(jax.lax.bitcast_convert_type(keys, jnp.int32)), jnp.uint32
+    )
+    return keys, keys_col, i * blk_s + _iota((blk_s, 1), 0)
 
 
 def _kernel(
@@ -174,7 +243,7 @@ def _kernel(
 ):
     """One (batch·kv-head) row of one-pass retrieval.
 
-    len_ref [1] int32 (SMEM); q_ref [rep, D]; codes/scale/zero: whole
+    len_ref [1, 1] int32 (SMEM); q_ref [rep, D]; codes/scale/zero: whole
     head-major slabs [BH, S/8|S/g, D] in ANY space (DMA'd blockwise);
     idx_ref [1, budget] int32; tau_ref [1, LANE] f32; m_ref [1, LANE]
     int32; codes_v/scale_v/zero_v: [2, ...] double-buffer scratch;
@@ -184,7 +253,7 @@ def _kernel(
     nb = S // blk_s
     n8 = blk_s // 8
     ng = blk_s // group
-    length = len_ref[0]
+    length = len_ref[0, 0]
     qbf = q_ref[...].astype(jnp.bfloat16)
 
     def block_copies(i, slot):
@@ -213,7 +282,8 @@ def _kernel(
             cp.wait()
 
     def block_keys(i):
-        """Monotone-uint32 keys of block i's masked kv scores: [1, blk_s].
+        """Monotone-uint32 keys of block i's masked kv scores (row and
+        column) and their positions.
 
         Scores exist only here, in VREGs, for the duration of one fold.
         """
@@ -234,8 +304,7 @@ def _kernel(
                 start_block(i + 1)
 
             wait_block(i)
-            keys, pos = block_keys(i)
-            return fold(keys, pos, carry)
+            return fold(block_keys(i), carry)
 
         return jax.lax.fori_loop(0, nb, body, init)
 
@@ -288,21 +357,23 @@ def fused_retrieve_hm(
         ),
         grid=(BH,),
         in_specs=[
-            pl.BlockSpec((None, 1), lambda b: (b, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec(
+                (None, 1, 1), lambda b: (b, 0, 0), memory_space=pltpu.SMEM
+            ),
             pl.BlockSpec((None, rep, D), lambda b: (b, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((1, budget), lambda b: (b, 0)),
-            pl.BlockSpec((1, LANE), lambda b: (b, 0)),
-            pl.BlockSpec((1, LANE), lambda b: (b, 0)),
+            pl.BlockSpec((None, 1, budget), lambda b: (b, 0, 0)),
+            pl.BlockSpec((None, 1, LANE), lambda b: (b, 0, 0)),
+            pl.BlockSpec((None, 1, LANE), lambda b: (b, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, budget), jnp.int32),
-            jax.ShapeDtypeStruct((BH, LANE), jnp.float32),
-            jax.ShapeDtypeStruct((BH, LANE), jnp.int32),
+            jax.ShapeDtypeStruct((BH, 1, budget), jnp.int32),
+            jax.ShapeDtypeStruct((BH, 1, LANE), jnp.float32),
+            jax.ShapeDtypeStruct((BH, 1, LANE), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((2, blk // 8, D), jnp.uint8),
@@ -311,8 +382,8 @@ def fused_retrieve_hm(
             pltpu.SemaphoreType.DMA((2, 3)),
         ],
         interpret=interpret,
-    )(lengths[:, None], q, codes, scale, zero)
-    return idx, tau[:, 0], m[:, 0]
+    )(lengths[:, None, None], q, codes, scale, zero)
+    return idx[:, 0], tau[:, 0, 0], m[:, 0, 0]
 
 
 # ------------------------------------------------------- page-table variant
@@ -326,10 +397,11 @@ def _paged_kernel(
 ):
     """One (batch, kv-head) row of one-pass retrieval over a *paged* pool.
 
-    bt_ref [n_btab] int32 (SMEM) — this request's block table row;
-    len_ref [1] int32 (SMEM); q_ref [rep, D]; codes/scale/zero: whole
-    paged side-car pools [N, bs/8|bs/g, Hkv, D] in ANY space; outputs and
-    scratch as in the contiguous kernel.  The per-row DMA stream walks
+    bt_ref [1, n_btab] int32 (SMEM) — this request's block table row;
+    len_ref [1, 1] int32 (SMEM); q_ref [rep, D]; codes/scale/zero: whole
+    paged side-car pools [N, bs/8|bs/g, Hkv, D] in ANY space; outputs as
+    in the contiguous kernel; scratch holds every kv head of a block
+    (``head_rows`` picks this row's head).  The per-row DMA stream walks
     ``block_table[b]`` instead of a contiguous slab: logical code block
     ``i`` is fetched from pool row ``bt[i]`` (unallocated entries point
     at the null block, whose garbage scores are masked by ``length``).
@@ -341,20 +413,20 @@ def _paged_kernel(
     bs = block_size
     n8 = bs // 8
     ng = bs // group
-    length = len_ref[0]
+    length = len_ref[0, 0]
     qbf = q_ref[...].astype(jnp.bfloat16)
 
     def block_copies(i, slot):
-        phys = bt_ref[i]
+        phys = bt_ref[0, i]
         return (
             pltpu.make_async_copy(
-                codes_hbm.at[phys, :, h, :], codes_v.at[slot], sems.at[slot, 0]
+                codes_hbm.at[phys], codes_v.at[slot], sems.at[slot, 0]
             ),
             pltpu.make_async_copy(
-                scale_hbm.at[phys, :, h, :], scale_v.at[slot], sems.at[slot, 1]
+                scale_hbm.at[phys], scale_v.at[slot], sems.at[slot, 1]
             ),
             pltpu.make_async_copy(
-                zero_hbm.at[phys, :, h, :], zero_v.at[slot], sems.at[slot, 2]
+                zero_hbm.at[phys], zero_v.at[slot], sems.at[slot, 2]
             ),
         )
 
@@ -369,7 +441,8 @@ def _paged_kernel(
     def block_keys(i):
         slot = jax.lax.rem(i, 2)
         s = score_block(
-            qbf, codes_v[slot], scale_v[slot], zero_v[slot], group=group
+            qbf, head_rows(codes_v[slot], h), head_rows(scale_v[slot], h),
+            head_rows(zero_v[slot], h), group=group,
         )                                                   # [rep, bs]
         return _masked_block_keys(s, i, bs, length, sink, recent, group_reduce)
 
@@ -382,8 +455,7 @@ def _paged_kernel(
                 start_block(i + 1)
 
             wait_block(i)
-            keys, pos = block_keys(i)
-            return fold(keys, pos, carry)
+            return fold(block_keys(i), carry)
 
         return jax.lax.fori_loop(0, n_btab, body, init)
 
@@ -440,30 +512,33 @@ def paged_fused_retrieve_hm(
         grid=(B, Hkv),
         in_specs=[
             pl.BlockSpec(
-                (None, n_btab), lambda b, h: (b, 0), memory_space=pltpu.SMEM
+                (None, 1, n_btab), lambda b, h: (b, 0, 0),
+                memory_space=pltpu.SMEM,
             ),
-            pl.BlockSpec((None, 1), lambda b, h: (b, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec(
+                (None, 1, 1), lambda b, h: (b, 0, 0), memory_space=pltpu.SMEM
+            ),
             pl.BlockSpec((None, None, rep, D), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((None, None, budget), lambda b, h: (b, h, 0)),
-            pl.BlockSpec((None, None, LANE), lambda b, h: (b, h, 0)),
-            pl.BlockSpec((None, None, LANE), lambda b, h: (b, h, 0)),
+            pl.BlockSpec((None, None, 1, budget), lambda b, h: (b, h, 0, 0)),
+            pl.BlockSpec((None, None, 1, LANE), lambda b, h: (b, h, 0, 0)),
+            pl.BlockSpec((None, None, 1, LANE), lambda b, h: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, budget), jnp.int32),
-            jax.ShapeDtypeStruct((B, Hkv, LANE), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, LANE), jnp.int32),
+            jax.ShapeDtypeStruct((B, Hkv, 1, budget), jnp.int32),
+            jax.ShapeDtypeStruct((B, Hkv, 1, LANE), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, 1, LANE), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, block_size // 8, D), jnp.uint8),
-            pltpu.VMEM((2, block_size // group, D), scale.dtype),
-            pltpu.VMEM((2, block_size // group, D), zero.dtype),
+            pltpu.VMEM((2, block_size // 8, Hkv, D), jnp.uint8),
+            pltpu.VMEM((2, block_size // group, Hkv, D), scale.dtype),
+            pltpu.VMEM((2, block_size // group, Hkv, D), zero.dtype),
             pltpu.SemaphoreType.DMA((2, 3)),
         ],
         interpret=interpret,
-    )(block_table, lengths[:, None], q, codes, scale, zero)
-    return idx, tau[:, :, 0], m[:, :, 0]
+    )(block_table[:, None], lengths[:, None, None], q, codes, scale, zero)
+    return idx[:, :, 0], tau[:, :, 0, 0], m[:, :, 0, 0]

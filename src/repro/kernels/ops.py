@@ -1,13 +1,15 @@
 """jit'd wrappers adapting cache layouts to the head-major Pallas kernels.
 
-On CPU (this container) kernels run in ``interpret=True`` mode — the
-kernel body executes as Python/jnp, validating the exact code that
-compiles for TPU.  On a TPU backend ``interpret`` flips off automatically.
+On the CPU backend (tests, CPU rehearsals) kernels run in
+``interpret=True`` mode — the kernel body executes as Python/jnp,
+validating the exact code that compiles for TPU.  Every other backend
+compiles them with Mosaic (``interpret=False``): a backend Mosaic cannot
+target fails loudly instead of being interpreted in silence.
 
 Layout note: the cache is seq-major [B, S, H, D] (sequence sharding);
 kernels want head-major [B·H, S, D] so the scan streams contiguously.
-The transposes below are the *baseline*; the §Perf layout iteration
-measures a head-major cache variant that removes them (EXPERIMENTS.md).
+The transposes below are the *baseline* of the slab layout; the paged
+pool is read in place.
 
 Cache-layout dispatch happens on :class:`repro.core.policy.CacheView`:
 ``retrieve`` / ``attend_selected`` read the slab-vs-paged choice off
@@ -31,8 +33,14 @@ from . import sparse_attention as _sa
 from . import topk_select as _tk
 
 
+# selected rows per fused select-and-attend grid step: each row's K and V
+# land in VMEM with all Hkv heads (2·Hkv·D·2 B), so 128 rows keep the
+# scratch and its f32 temporaries near 1 MiB at Hkv = 16, D = 128
+ATTEND_BLK = 128
+
+
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def fier_score(q: jax.Array, qk: QuantizedKeys, *, blk_s: int = 512) -> jax.Array:
@@ -199,7 +207,7 @@ def attend_selected(
     view: CacheView,
     idx: jax.Array,
     *,
-    blk_k: int = 1024,
+    blk_k: int = ATTEND_BLK,
 ) -> jax.Array:
     """Fused select-and-attend over a ``CacheView``: the selected rows are
     gathered *inside* the kernel (per-row DMA; paged layout additionally
@@ -247,7 +255,7 @@ def fier_decode_one_pass(
     group_reduce: str = "max",
     sink: int = 0,
     recent: int = 0,
-    blk_k: int = 1024,
+    blk_k: int = ATTEND_BLK,
 ) -> jax.Array:
     """The ``one_pass`` FIER pipeline — the serving decode fast path for
     both layouts: single-kernel retrieval (per-token scores never in
@@ -269,7 +277,7 @@ def fier_decode_two_pass(
     group_reduce: str = "max",
     sink: int = 0,
     recent: int = 0,
-    blk_k: int = 1024,
+    blk_k: int = ATTEND_BLK,
 ) -> jax.Array:
     """The ``two_pass`` FIER pipeline (slab layout only): score-scan
     kernel → threshold top-k kernel (f32 score tensors materialised
@@ -300,7 +308,7 @@ def fused_sparse_attention(
     idx: jax.Array,
     length: jax.Array | None,
     *,
-    blk_k: int = 1024,
+    blk_k: int = ATTEND_BLK,
 ) -> jax.Array:
     """Deprecated: ``attend_selected(q, CacheView.slab(K, V), idx)``."""
     _warn_deprecated(
@@ -375,7 +383,7 @@ def fused_fier_attention_decode(
     group_reduce: str = "max",
     sink: int = 0,
     recent: int = 0,
-    blk_k: int = 1024,
+    blk_k: int = ATTEND_BLK,
     one_pass: bool = True,
 ) -> jax.Array:
     """Deprecated: ``fier_decode_one_pass`` / ``fier_decode_two_pass`` on
@@ -427,7 +435,7 @@ def paged_fused_sparse_attention(
     idx: jax.Array,
     length: jax.Array | None,
     *,
-    blk_k: int = 1024,
+    blk_k: int = ATTEND_BLK,
 ) -> jax.Array:
     """Deprecated: ``attend_selected`` on a paged view."""
     _warn_deprecated(
@@ -450,7 +458,7 @@ def paged_fused_fier_attention_decode(
     group_reduce: str = "max",
     sink: int = 0,
     recent: int = 0,
-    blk_k: int = 1024,
+    blk_k: int = ATTEND_BLK,
 ) -> jax.Array:
     """Deprecated: ``fier_decode_one_pass`` on a paged ``CacheView`` (or
     ``policy.decode_attention`` with a paged plan)."""

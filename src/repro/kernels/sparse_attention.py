@@ -13,11 +13,13 @@ GPU).  Kept as the fallback and as the shape the jnp oracle mirrors.
 selected row HBM→VMEM with per-row async DMA inside the kernel.  No K'/V'
 copy ever exists in HBM: the only cache traffic is budget rows *read*
 directly from the slabs.  The gather loop double-issues the K and V row
-copies so both are in flight per step.
+copies so both are in flight per step.  Mosaic DMAs whole (Hkv, D) tiles,
+so each copy brings every kv head of the row and the kernel picks its
+head in VMEM (DESIGN.md §Chip layouts).
 
-Both use the same online-softmax over budget blocks: one VMEM tile holds
-a (kv-head, blk_k) stripe — budget=4096, D=128 bf16 → 1 MiB K + 1 MiB V —
-and larger budgets carry (m, d) across blocks.
+Both use the same online-softmax over budget blocks of blk_k rows
+(``ops.ATTEND_BLK`` = 128: with all 16 heads of each row, K + V scratch
+is 1 MiB at D = 128 bf16); larger budgets carry (m, d) across blocks.
 
 Grids: (B·Hkv, budget/blk_k) unfused; (B, Hkv, budget/blk_k) fused (the
 fused kernel indexes the seq-major [B, S, Hkv, D] slabs directly, so the
@@ -32,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .fused_retrieval import head_rows
 
 NEG_INF = -1e30
 
@@ -78,7 +82,8 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, m_ref, d_ref, *, scale):
 
     _softmax_accumulate(
         q_ref[...].astype(jnp.float32), k_ref[...], v_ref[...],
-        mask_ref[...] > 0, out_ref, m_ref, d_ref, scale=scale,
+        mask_ref[...].astype(jnp.int32) > 0, out_ref, m_ref, d_ref,
+        scale=scale,
     )
 
 
@@ -130,15 +135,25 @@ def sparse_attention_hm(
 
 # ------------------------------------------------------ fused select+attend
 
+def _idx_blocks(idx: jax.Array, blk_k: int) -> jax.Array:
+    """[B, Hkv, budget] → [B, Hkv, budget/blk_k, 1, blk_k]: one grid step's
+    indices are a whole trailing (1, blk_k) tile, the block shape Mosaic
+    accepts for an SMEM operand."""
+    B, Hkv, budget = idx.shape
+    return idx.reshape(B, Hkv, budget // blk_k, 1, blk_k)
+
+
 def _fused_kernel(
     idx_ref, q_ref, mask_ref, k_hbm, v_hbm, out_ref, m_ref, d_ref,
     k_vmem, v_vmem, sems, *, scale,
 ):
     """One (batch, kv-head, budget-block) step of fused select-and-attend.
 
-    idx_ref [blk_k] int32 (SMEM); q [rep, D]; mask int8 [1, blk_k];
+    idx_ref [1, blk_k] int32 (SMEM); q [rep, D]; mask int8 [1, blk_k];
     k_hbm/v_hbm: *whole* seq-major cache slabs [B, S, Hkv, D] (ANY space —
-    never staged through VMEM wholesale); k_vmem/v_vmem [blk_k, D] scratch;
+    never staged through VMEM wholesale); k_vmem/v_vmem [blk_k, Hkv, D]
+    scratch — every kv head of each selected row, since Mosaic DMAs whole
+    (Hkv, D) tiles — from which ``head_rows`` picks head ``h``;
     sems: [2, 2] DMA semaphores — (slot = row parity) × (K, V) — so the
     gather loop keeps the next row's copies in flight while waiting on
     the current row's (double-buffered, not serial round-trips).
@@ -159,14 +174,14 @@ def _fused_kernel(
         """The (K, V) row-i DMA descriptors; slot = i mod 2 double-buffers
         the semaphores so row i+1's copies are in flight while row i's
         are being waited on."""
-        row = idx_ref[i]
+        row = idx_ref[0, i]
         slot = jax.lax.rem(i, 2)
         kcp = pltpu.make_async_copy(
-            k_hbm.at[b, pl.ds(row, 1), h, :], k_vmem.at[pl.ds(i, 1), :],
+            k_hbm.at[b, pl.ds(row, 1)], k_vmem.at[pl.ds(i, 1)],
             sems.at[slot, 0],
         )
         vcp = pltpu.make_async_copy(
-            v_hbm.at[b, pl.ds(row, 1), h, :], v_vmem.at[pl.ds(i, 1), :],
+            v_hbm.at[b, pl.ds(row, 1)], v_vmem.at[pl.ds(i, 1)],
             sems.at[slot, 1],
         )
         return kcp, vcp
@@ -191,8 +206,9 @@ def _fused_kernel(
     jax.lax.fori_loop(0, blk_k, gather, 0)
 
     _softmax_accumulate(
-        q_ref[...].astype(jnp.float32), k_vmem[...], v_vmem[...],
-        mask_ref[...] > 0, out_ref, m_ref, d_ref, scale=scale,
+        q_ref[...].astype(jnp.float32), head_rows(k_vmem[...], h),
+        head_rows(v_vmem[...], h), mask_ref[...].astype(jnp.int32) > 0,
+        out_ref, m_ref, d_ref, scale=scale,
     )
 
 
@@ -230,13 +246,13 @@ def fused_sparse_attention_hm(
         grid=grid,
         in_specs=[
             pl.BlockSpec(
-                (None, None, blk_k), lambda b, h, j: (b, h, j),
+                (None, None, None, 1, blk_k), lambda b, h, j: (b, h, j, 0, 0),
                 memory_space=pltpu.SMEM,
             ),
             pl.BlockSpec((None, None, rep, D), lambda b, h, j: (b, h, 0, 0)),
             pl.BlockSpec((None, None, 1, blk_k), lambda b, h, j: (b, h, 0, j)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((None, None, rep, D), lambda b, h, j: (b, h, 0, 0)),
@@ -249,12 +265,12 @@ def fused_sparse_attention_hm(
             jax.ShapeDtypeStruct((B, Hkv, rep, 128), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((blk_k, D), K.dtype),
-            pltpu.VMEM((blk_k, D), V.dtype),
+            pltpu.VMEM((blk_k, Hkv, D), K.dtype),
+            pltpu.VMEM((blk_k, Hkv, D), V.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
         interpret=interpret,
-    )(idx, q, mask, K, V)
+    )(_idx_blocks(idx, blk_k), q, mask, K, V)
     den = jnp.maximum(d[..., 0], 1e-30)
     return out / den[..., None]
 
@@ -271,7 +287,7 @@ def _paged_fused_kernel(
     operands are the block-pool slabs [N, bs, Hkv, D] (ANY space) and the
     selected *logical* token index ``t`` is translated in-kernel to
     ``(block_table[t // bs], t % bs)`` via the SMEM-resident table row
-    ``bt_ref [n_btab]``.  The online-softmax epilogue is shared, so paged
+    ``bt_ref [1, n_btab]``.  The online-softmax epilogue is shared, so paged
     and slab outputs are bit-identical at equal ``blk_k``.
     """
     h = pl.program_id(1)
@@ -286,16 +302,16 @@ def _paged_fused_kernel(
     blk_k = k_vmem.shape[0]
 
     def row_copies(i):
-        row = idx_ref[i]
-        phys = bt_ref[row // block_size]
+        row = idx_ref[0, i]
+        phys = bt_ref[0, row // block_size]
         off = jax.lax.rem(row, block_size)
         slot = jax.lax.rem(i, 2)
         kcp = pltpu.make_async_copy(
-            k_hbm.at[phys, pl.ds(off, 1), h, :], k_vmem.at[pl.ds(i, 1), :],
+            k_hbm.at[phys, pl.ds(off, 1)], k_vmem.at[pl.ds(i, 1)],
             sems.at[slot, 0],
         )
         vcp = pltpu.make_async_copy(
-            v_hbm.at[phys, pl.ds(off, 1), h, :], v_vmem.at[pl.ds(i, 1), :],
+            v_hbm.at[phys, pl.ds(off, 1)], v_vmem.at[pl.ds(i, 1)],
             sems.at[slot, 1],
         )
         return kcp, vcp
@@ -320,8 +336,9 @@ def _paged_fused_kernel(
     jax.lax.fori_loop(0, blk_k, gather, 0)
 
     _softmax_accumulate(
-        q_ref[...].astype(jnp.float32), k_vmem[...], v_vmem[...],
-        mask_ref[...] > 0, out_ref, m_ref, d_ref, scale=scale,
+        q_ref[...].astype(jnp.float32), head_rows(k_vmem[...], h),
+        head_rows(v_vmem[...], h), mask_ref[...].astype(jnp.int32) > 0,
+        out_ref, m_ref, d_ref, scale=scale,
     )
 
 
@@ -360,17 +377,17 @@ def paged_fused_sparse_attention_hm(
         grid=grid,
         in_specs=[
             pl.BlockSpec(
-                (None, n_btab), lambda b, h, j: (b, 0),
+                (None, 1, n_btab), lambda b, h, j: (b, 0, 0),
                 memory_space=pltpu.SMEM,
             ),
             pl.BlockSpec(
-                (None, None, blk_k), lambda b, h, j: (b, h, j),
+                (None, None, None, 1, blk_k), lambda b, h, j: (b, h, j, 0, 0),
                 memory_space=pltpu.SMEM,
             ),
             pl.BlockSpec((None, None, rep, D), lambda b, h, j: (b, h, 0, 0)),
             pl.BlockSpec((None, None, 1, blk_k), lambda b, h, j: (b, h, 0, j)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((None, None, rep, D), lambda b, h, j: (b, h, 0, 0)),
@@ -383,11 +400,11 @@ def paged_fused_sparse_attention_hm(
             jax.ShapeDtypeStruct((B, Hkv, rep, 128), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((blk_k, D), k_pool.dtype),
-            pltpu.VMEM((blk_k, D), v_pool.dtype),
+            pltpu.VMEM((blk_k, Hkv, D), k_pool.dtype),
+            pltpu.VMEM((blk_k, Hkv, D), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
         interpret=interpret,
-    )(block_table, idx, q, mask, k_pool, v_pool)
+    )(block_table[:, None], _idx_blocks(idx, blk_k), q, mask, k_pool, v_pool)
     den = jnp.maximum(d[..., 0], 1e-30)
     return out / den[..., None]

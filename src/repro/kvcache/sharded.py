@@ -48,7 +48,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import policy as core_policy
 
 from .paged import BlockAllocator, EvictedBlock, paged_append_kv, \
@@ -198,7 +197,7 @@ def sharded_paged_decode_step(
         )
         return out, k2, v2, meta2
 
-    f = shard_map(
+    f = jax.shard_map(
         body,
         mesh=spec.mesh,
         in_specs=(q_spec, new_spec, new_spec, pool_spec, pool_spec,

@@ -1,7 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede any jax-importing import: jax locks the device count on
 # first init, and the production meshes below need 512 host placeholders.
+# This is a CPU tool: pinning the platform (inherited by the --all
+# children) keeps it off any accelerator the host has.
 
 import argparse      # noqa: E402
 import json          # noqa: E402

@@ -16,12 +16,14 @@ import numpy as np
 from repro.configs import get_config, reduced_config
 from repro.core.policy import PolicyConfig
 from repro.data.pipeline import lm_tokens
+from repro.launch.compile_cache import enable_compilation_cache
 from repro.launch.mesh import batch_axes, make_local_mesh
 from repro.models import DistConfig, build_model
 from repro.serving import ContinuousScheduler, Engine, Request, SamplingConfig
 
 
 def main() -> None:
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -47,14 +49,12 @@ def main() -> None:
     layout = "paged" if args.paged else "slab"
     pol = None
     if args.policy != "full" and not cfg.attention_free:
-        # paged fier serves through the one-pass kernel pipeline (the
-        # only paged fier pipeline in the capability matrix besides the
-        # reference oracle); slab mode keeps the reference pipeline so
-        # the driver exercises both ends of the matrix
+        # both layouts serve through the one-pass kernel pipeline; the jnp
+        # reference pipeline is the tests' oracle, not a serving path
         pol = PolicyConfig(
             kind=args.policy, budget=args.budget, group=args.group,
             skip_layers=1 if args.reduced else 2,
-            pipeline="one_pass" if args.paged else "reference",
+            pipeline="one_pass" if args.policy == "fier" else "reference",
             layout=layout,
             block_size=args.block_size, pool_blocks=args.pool_blocks,
         )

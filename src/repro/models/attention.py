@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.core import distributed as dist
 from repro.core import policy as core_policy
@@ -313,7 +312,7 @@ def _sharded_decode_step(
         return out, K2, V2, meta2
 
     meta_spec = jax.tree.map(lambda _: kv_spec, meta)
-    f = shard_map(
+    f = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(q_spec, q_spec, q_spec, kv_spec, kv_spec, meta_spec, q_spec),

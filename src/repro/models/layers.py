@@ -29,23 +29,15 @@ def wuse(w: jax.Array, tp_dim: int = -1) -> jax.Array:
     weight all-gather instead.  No-op without an active mesh (unit tests,
     single device) or for shard_map-managed weights (MoE EP).
     """
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except AttributeError:  # older jax
-        return w
-    if mesh is None or not getattr(mesh, "axis_names", ()):
-        return w
-    if "model" not in mesh.axis_names or w.ndim < 2:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "model" not in mesh.axis_names or w.ndim < 2:
         return w
     from jax.sharding import PartitionSpec as P
 
     spec = [None] * w.ndim
     d = tp_dim if tp_dim >= 0 else w.ndim + tp_dim
     spec[d] = "model"
-    try:
-        return jax.lax.with_sharding_constraint(w, P(*spec))
-    except Exception:
-        return w
+    return jax.lax.with_sharding_constraint(w, P(*spec))
 
 
 # --------------------------------------------------------------------- norms

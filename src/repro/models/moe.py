@@ -15,7 +15,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 
 from .layers import init_linear
@@ -158,7 +157,7 @@ def moe_apply_ep(
         aux = jax.lax.pmean(aux_local, all_axes)
         return y, aux
 
-    f = shard_map(
+    f = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

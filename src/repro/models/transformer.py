@@ -286,6 +286,10 @@ def build(
             bs = pol.block_size
             phys = table_row[positions[0] // bs]                 # [n]
             offs = positions[0] % bs
+        if shard is not None:           # paged layout on a mesh
+            replicated = jax.sharding.NamedSharding(
+                shard.mesh, jax.sharding.PartitionSpec()
+            )
 
         def chunk_body(hc, xs):
             lp, lc = xs
@@ -301,11 +305,8 @@ def build(
                     # gathered from a mesh-sharded pool: replicate before
                     # attention so the wo contraction reduces in the same
                     # order as the single-device prefill (bit-identity)
-                    rep = jax.sharding.NamedSharding(
-                        shard.mesh, jax.sharding.PartitionSpec()
-                    )
-                    Kl = jax.lax.with_sharding_constraint(Kl, rep)
-                    Vl = jax.lax.with_sharding_constraint(Vl, rep)
+                    Kl = jax.lax.with_sharding_constraint(Kl, replicated)
+                    Vl = jax.lax.with_sharding_constraint(Vl, replicated)
             else:
                 lck = jax.lax.dynamic_update_slice(lc["k"], kc, (slot, start, 0, 0))
                 lcv = jax.lax.dynamic_update_slice(lc["v"], vc, (slot, start, 0, 0))
@@ -316,6 +317,11 @@ def build(
             o = attn.flash_attention(
                 q, Kl, Vl, causal=True, q_offset=start, bias_mask=valid
             )
+            if shard is not None:
+                # q inherits the pool's head sharding from the fused qkv
+                # projection; left sharded, wo would contract over a split
+                # head axis and all-reduce partial sums in bf16
+                o = jax.lax.with_sharding_constraint(o, replicated)
             o = o.reshape(1, n, cfg.n_heads * cfg.d_head) @ lp["attn"]["wo"].astype(hc.dtype)
             hc = hc + o
             y, _ = _ffn(lp, apply_norm(hc, lp["norm2"], cfg.norm), 1, n)
@@ -360,12 +366,12 @@ def build(
                         )
             return hc + y, new_lc
 
-        front_p = jax.tree.map(lambda a: a[:skip], params["layers"])
-        rest_p = jax.tree.map(lambda a: a[skip:], params["layers"])
-        h, front_cache = maybe_scan(
-            chunk_body, h, (front_p, cache["front"])
-        ) if skip else (h, cache["front"])
-        h, rest_cache = maybe_scan(chunk_body, h, (rest_p, cache["rest"]))
+        h, front_cache = _layer_loop(
+            chunk_body, h, params["layers"], cache["front"], 0
+        )
+        h, rest_cache = _layer_loop(
+            chunk_body, h, params["layers"], cache["rest"], skip
+        )
         new_cache = dict(cache, front=front_cache, rest=rest_cache)
         if not final:
             return None, new_cache
@@ -401,13 +407,13 @@ def build(
 
             return body
 
-        front_params = jax.tree.map(lambda a: a[:skip], params["layers"])
-        rest_params = jax.tree.map(lambda a: a[skip:], params["layers"])
-        h, front_cache = maybe_scan(
-            mk_body(plan_full, use_dist=False), x, (front_params, cache["front"])
-        ) if skip else (x, cache["front"])
-        h, rest_cache = maybe_scan(
-            mk_body(plan, use_dist=True), h, (rest_params, cache["rest"])
+        h, front_cache = _layer_loop(
+            mk_body(plan_full, use_dist=False), x, params["layers"],
+            cache["front"], 0,
+        )
+        h, rest_cache = _layer_loop(
+            mk_body(plan, use_dist=True), h, params["layers"], cache["rest"],
+            skip,
         )
         h = apply_norm(h, params["final_norm"], cfg.norm)[:, 0]
         logits = _masked_logits(h, _head(params), cfg.vocab, Vp)
@@ -432,6 +438,46 @@ def build(
         plan=plan,
         prefill_chunk=prefill_chunk,
     )
+
+
+# ------------------------------------------------------------- layer loop
+
+def _layer_loop(body, h, layer_params, cache_stack, first: int):
+    """Run ``body(h, (params_l, cache_l)) -> (h, cache_l)`` over the layers
+    of a stacked cache, with the stack carried through the loop and each
+    layer written back in place.
+
+    Layer ``l`` of the stack uses ``layer_params`` layer ``first + l``,
+    read by dynamic index from the full stack.  Scanning the cache as
+    ``xs``/``ys`` (and slicing ``params[first:]``) instead makes XLA
+    materialise a second copy of the whole KV pool and of the weights: at
+    olmo-1b widths with a 4 GiB pool that no longer fits a 16 GB chip.
+    """
+    leaves = jax.tree.leaves(cache_stack)
+    n = leaves[0].shape[0] if leaves else 0
+    if n == 0:
+        return h, cache_stack
+
+    def take(tree, i):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), tree
+        )
+
+    def step(carry, i):
+        hc, stack = carry
+        hc, lc = body(hc, (take(layer_params, first + i), take(stack, i)))
+        stack = jax.tree.map(
+            lambda a, x: jax.lax.dynamic_update_index_in_dim(
+                a, x.astype(a.dtype), i, 0
+            ),
+            stack, lc,
+        )
+        return (hc, stack), None
+
+    (h, cache_stack), _ = maybe_scan(
+        step, (h, cache_stack), jnp.arange(n, dtype=jnp.int32)
+    )
+    return h, cache_stack
 
 
 # ---------------------------------------------------------------- CE / head

@@ -444,6 +444,15 @@ class Engine:
                     f"degree {spec.n_tp} (mesh axes "
                     f"{spec.tp_axes!r})"
                 )
+            if not pol.pool_blocks:
+                # worst-case pool with one null block per DP shard (the
+                # bundle's own default reserves a single null block, which
+                # does not split over the DP shards)
+                pol = dataclasses.replace(
+                    pol,
+                    pool_blocks=n_slots * (capacity // pol.block_size)
+                    + spec.n_dp,
+                )
             # mesh=None on the DistConfig: the paged shard path carries
             # its mesh on the spec; DistConfig.mesh would additionally
             # arm the slab sequence-sharding machinery (activation
@@ -485,6 +494,18 @@ class Engine:
 
             cache = shard_cache(cache, self.shard)
         return cache
+
+    def place_params(self, params):
+        """Put ``params`` where the decode step reads them: replicated on
+        every device of a mesh-sharded engine's mesh (once, instead of a
+        transfer on every jitted call); unchanged on a single device."""
+        if self.shard is None:
+            return params
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        return jax.device_put(
+            params, NamedSharding(self.shard.mesh, PartitionSpec())
+        )
 
     def prefill_batch(self, params, batch):
         """Whole-batch prefill (offline / static batching path)."""

@@ -58,16 +58,17 @@ def test_elastic_reshard_between_meshes():
 import tempfile
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.checkpoint import CheckpointManager
 
 d = tempfile.mkdtemp()
 mgr = CheckpointManager(d)
 x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
-mesh4 = jax.make_mesh((4,), ("data",))
+mesh4 = make_mesh((4,), ("data",))
 x4 = jax.device_put(x, NamedSharding(mesh4, P("data")))
 mgr.save(5, {"x": x4})
 
-mesh2 = jax.make_mesh((2,), ("data",))
+mesh2 = make_mesh((2,), ("data",))
 sh2 = {"x": NamedSharding(mesh2, P("data"))}
 back = mgr.restore(5, {"x": x}, sharding=sh2)
 np.testing.assert_array_equal(np.asarray(back["x"]), np.asarray(x))

@@ -8,7 +8,7 @@ from conftest import run_in_subprocess
 _COMMON = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core import quantize as qz, retrieval as rt, distributed as dist
 
 B, S, Hkv, Hq, D, g = 2, 256, 2, 4, 32, 8
@@ -18,7 +18,7 @@ V = jax.random.normal(ks[1], (B, S, Hkv, D))
 q = jax.random.normal(ks[2], (B, Hq, D))
 length = jnp.array([256, 200], jnp.int32)
 qk = qz.quantize(K, g)
-mesh = jax.make_mesh((4,), ("model",))
+mesh = make_mesh((4,), ("model",))
 n_shards = 4
 S_loc = S // n_shards
 
@@ -30,7 +30,7 @@ def sharded(mode, budget):
             q_l, K_l, V_l, meta_l, budget, len_l, axis=("model",),
             shard_start=start, n_shards=n_shards, mode=mode)
     kv = P(None, "model")
-    f = shard_map(body, mesh=mesh,
+    f = jax.shard_map(body, mesh=mesh,
         in_specs=(P(), kv, kv, kv, kv, kv, P()), out_specs=P(), check_vma=False)
     return jax.jit(f)(q, K, V, qk.codes, qk.scale, qk.zero, length)
 
@@ -40,8 +40,8 @@ def full_sharded():
         return dist.full_decode_sharded(q_l, K_l, V_l, len_l, axis=("model",),
                                         shard_start=start)
     kv = P(None, "model")
-    f = shard_map(body, mesh=mesh, in_specs=(P(), kv, kv, P()),
-                      out_specs=P(), check_vma=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P(), kv, kv, P()),
+                          out_specs=P(), check_vma=False)
     return jax.jit(f)(q, K, V, length)
 """
 
@@ -98,6 +98,7 @@ def test_model_decode_with_seq_sharded_cache():
     run_in_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.configs import reduced_config
 from repro.core.policy import PolicyConfig
 from repro.models import build_model, DistConfig
@@ -105,7 +106,7 @@ from repro.launch import sharding as shard
 
 cfg = reduced_config("olmo-1b")
 pol = PolicyConfig(kind="fier", budget=16, group=8, skip_layers=1)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 
 bundle_plain = build_model(cfg, pol)
 # exact mode: global-top-k threshold via all-gather — must match the

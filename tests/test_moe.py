@@ -48,6 +48,7 @@ def test_ep_matches_scatter_multidevice():
         """
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.configs import reduced_config
 from repro.models import moe as moe_mod
 
@@ -57,7 +58,7 @@ p = moe_mod.init_moe(k1, cfg)
 x = jax.random.normal(k2, (64, cfg.d_model), jnp.float32)
 y_ref, aux_ref = moe_mod.moe_apply(x, p, cfg)
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 y_ep, aux_ep = jax.jit(lambda x, p: moe_mod.moe_apply_ep(
     x, p, cfg, mesh=mesh, token_axes=("data",), model_axis="model"))(x, p)
 np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_ep), atol=2e-4, rtol=2e-4)
@@ -77,6 +78,7 @@ def test_ep_with_fsdp_gather_multidevice():
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.configs import reduced_config
 from repro.models import moe as moe_mod
 
@@ -86,7 +88,7 @@ p = moe_mod.init_moe(k1, cfg)
 x = jax.random.normal(k2, (64, cfg.d_model), jnp.float32)
 y_ref, _ = moe_mod.moe_apply(x, p, cfg)
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 sh = {
     "router": NamedSharding(mesh, P()),
     "w1": NamedSharding(mesh, P("model", "data", None)),
@@ -110,6 +112,7 @@ def test_ep_gradients_flow():
     run_in_subprocess(
         """
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.configs import reduced_config
 from repro.models import moe as moe_mod
 
@@ -117,7 +120,7 @@ cfg = reduced_config("granite-moe-1b-a400m")
 k1, k2 = jax.random.split(jax.random.PRNGKey(0))
 p = moe_mod.init_moe(k1, cfg)
 x = jax.random.normal(k2, (64, cfg.d_model), jnp.float32)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 
 def loss(p, x):
     y, aux = moe_mod.moe_apply_ep(x, p, cfg, mesh=mesh, token_axes=("data",),

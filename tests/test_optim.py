@@ -83,16 +83,16 @@ def test_compressed_psum_multidevice():
     run_in_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from repro.launch.mesh import make_mesh
 from repro.optim import compressed_psum
 
-mesh = jax.make_mesh((4,), ("data",))
+mesh = make_mesh((4,), ("data",))
 x = jax.random.normal(jax.random.PRNGKey(0), (4, 64))
 
 def body(xl):
     return compressed_psum(xl[0], "data")
 
-f = shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P(),
+f = jax.shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P(),
                   check_vma=False)
 got = jax.jit(f)(x)
 want = np.mean([np.sign(np.asarray(x[i])) * np.abs(np.asarray(x[i])).mean()

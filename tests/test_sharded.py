@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+
 # unlike test_property.py this module holds more than property tests, so
 # a missing hypothesis skips only the selection-equivalence property
 # (declared in the `test` extra; CI installs it) instead of the module
@@ -36,6 +37,7 @@ from repro.core.policy import (
 )
 from repro.kvcache.paged import AllocatorAuditError
 from repro.kvcache.sharded import ShardSpec, ShardedBlockAllocator
+from repro.launch.mesh import make_mesh
 from repro.serving import Engine
 
 _BENCH_DIR = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
@@ -43,7 +45,7 @@ _BENCH_DIR = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
 
 def _mesh11():
     # single-device mesh: enough for spec/plan validation in the fast lane
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _pol(kind="fier", layout="paged", pipeline="reference", block_size=8):
@@ -137,7 +139,7 @@ def test_engine_build_mesh_validation():
     with pytest.raises(ValueError, match="must be named"):
         Engine.build(cfg, n_slots=2, capacity=64,
                      policy=_pol(), layout="paged",
-                     mesh=jax.make_mesh((1,), ("expert",)))
+                     mesh=make_mesh((1,), ("expert",)))
 
 
 # -------------------------------------------------- ShardedBlockAllocator
@@ -358,6 +360,7 @@ else:  # keep the skip visible in reports when hypothesis is absent
 _DRIVER = """
 import dataclasses
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.configs import reduced_config
 from repro.serving import Engine
 from repro.serving.engine import serving_policy
@@ -413,9 +416,9 @@ def test_sharded_decode_bit_identical_to_oracle():
     oracle (fier backend, reference pipeline), with a clean audit."""
     run_in_subprocess(_DRIVER + """
 base = run(None)
-check("tp2", base, run(jax.make_mesh((2,), ("model",))))
-check("dp2", base, run(jax.make_mesh((2,), ("data",))))
-check("tp2xdp2", base, run(jax.make_mesh((2, 2), ("data", "model"))))
+check("tp2", base, run(make_mesh((2,), ("model",))))
+check("dp2", base, run(make_mesh((2,), ("data",))))
+check("tp2xdp2", base, run(make_mesh((2, 2), ("data", "model"))))
 """)
 
 
@@ -424,7 +427,7 @@ def test_sharded_pipelines_and_backends_bit_identical():
     sharded through the same plan surface; a slot homed on DP shard 1
     is bit-identical to the slot-0 single-device run."""
     run_in_subprocess(_DRIVER + """
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 for kind, pipeline in [("fier", "one_pass"), ("full", "reference")]:
     base = run(None, pipeline=pipeline, kind=kind)
     got = run(mesh, pipeline=pipeline, kind=kind, slot=3)
@@ -439,14 +442,14 @@ def test_sharded_chunked_prefill_bit_identical():
     run_in_subprocess(_DRIVER + """
 base = run(None, chunked=True)
 check("chunked tp2xdp2",
-      base, run(jax.make_mesh((2, 2), ("data", "model")), chunked=True,
+      base, run(make_mesh((2, 2), ("data", "model")), chunked=True,
                 slot=2))
 """)
 
 
 def test_sharded_tp_divisibility_error():
     run_in_subprocess(_DRIVER + """
-mesh3 = jax.make_mesh((3,), ("model",))
+mesh3 = make_mesh((3,), ("model",))
 try:
     Engine.build(cfg, n_slots=2, capacity=64,
                  policy=serving_policy(budget=16, skip_layers=1),
@@ -470,6 +473,7 @@ import sys
 sys.path.insert(0, %r)
 from flopcount import count_fn_score_bytes
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import quantize as qz
 from repro.core.policy import DecodePlan, PolicyConfig
 from repro.kvcache.sharded import ShardSpec, sharded_paged_decode_step
@@ -504,7 +508,7 @@ k_pool, v_pool = to_pool(K), to_pool(V)
 meta = qz.QuantizedKeys(to_pool(qk.codes), to_pool(qk.scale),
                         to_pool(qk.zero), g)
 length = jnp.full((B,), S - 1, jnp.int32)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 spec = ShardSpec(mesh=mesh, tp_axes=("model",), dp_axes=("data",))
 
 def count(pipeline):
@@ -535,6 +539,7 @@ def test_sharded_chaos_audits_clean():
     run_in_subprocess("""
 import warnings
 import jax
+from repro.launch.mesh import make_mesh
 from repro.configs import reduced_config
 from repro.core.policy import PolicyConfig
 from repro.serving import (
@@ -547,7 +552,7 @@ pol = PolicyConfig(
     pipeline="reference", layout="paged", block_size=8, pool_blocks=40,
 )
 eng = Engine.build(cfg, n_slots=4, capacity=64, policy=pol,
-                   mesh=jax.make_mesh((2,), ("data",)))
+                   mesh=make_mesh((2,), ("data",)))
 params = eng.bundle.init(jax.random.PRNGKey(0))
 reqs = [Request(rid=i, tokens=list(range(2 + i, 12 + i)), max_new=12)
         for i in range(4)]

@@ -4,12 +4,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.compat import abstract_mesh
+from jax.sharding import AbstractMesh
 from repro.configs import ARCHS, get_config, padded_vocab
 from repro.launch.sharding import param_pspec, _path_str
 
-MESH_1POD = abstract_mesh((16, 16), ("data", "model"))
-MESH_2POD = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH_1POD = AbstractMesh((16, 16), ("data", "model"))
+MESH_2POD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _axis_size(mesh, name):
