@@ -6,16 +6,21 @@ engine and the scheduler share its :class:`~repro.obs.metrics.MetricsRegistry`
 Prometheus-text + JSON exposition) and its
 :class:`~repro.obs.tracing.Tracer` (request-lifecycle spans and scheduler
 events on the virtual token clock, exported as Chrome trace-event /
-Perfetto JSON or JSONL).  ``introspect=True`` additionally attaches a
+Perfetto JSON).  ``introspect=True`` additionally attaches a
 :class:`~repro.obs.introspect.RetrievalIntrospector` that samples the
 FIER retrieval stage per decode step (budget utilization, τ thresholds,
 oracle overlap, recaptured attention mass) into the same registry.
 
+Both tracers also hand out ``tracer.span(name, **args)``: a wall-clock
+span on the profiler's clock (``jax.profiler.TraceAnnotation``), always
+emitted, recorded only while a profile runs.  The scheduler's
+``serve.*`` spans are these.
+
 The default is **disabled**: ``Observability.disabled()`` (what an
 engine constructs when none is passed) hands out no-op instruments and
-the null tracer, so un-instrumented serving runs the same host work and
-the same jitted functions as before the subsystem existed — gated by
-the overhead/compile-count tests in tests/test_obs.py.
+the null tracer, so un-instrumented serving runs the same host work
+plus the inactive wall-clock spans, and the same jitted functions —
+gated by the overhead/compile-count tests in tests/test_obs.py.
 
 See DESIGN.md §Observability and ``tools/obs_report.py``.
 """

@@ -23,11 +23,23 @@ Track layout: requests live on ``pid=1`` with ``tid = rid`` (one lane per
 request in Perfetto); scheduler-global events on ``pid=0, tid=0``;
 counter tracks on ``pid=0``.  Export: :meth:`Tracer.to_chrome_trace`
 (the ``{"traceEvents": [...]}`` JSON Perfetto loads — virtual ts maps to
-µs) and :meth:`Tracer.to_jsonl` (one event per line for grep/pandas).
+µs).
+
+Wall-clock spans are a separate, always-on channel: :func:`span` (also
+``Tracer.span`` / the null tracer's) returns a
+``jax.profiler.TraceAnnotation``, so the span lands in the profiler's
+host plane on the same clock as the device ops, with its keyword args as
+the event's stats.  Outside a profile a span costs about a microsecond
+and records nothing; it never enters ``Tracer.events``, whose clock is
+the virtual one.  :func:`install_gc_spans` adds a ``serve.gc`` span
+around every pass of Python's garbage collector.  JAX is imported on the
+first span, so the stdlib-only tools that import this module never load
+it.
 """
 from __future__ import annotations
 
 import dataclasses
+import gc
 import json
 import math
 import time
@@ -38,6 +50,47 @@ PID_SCHED = 0
 PID_REQUEST = 1
 
 _CHROME_PHASES = ("X", "B", "E", "i", "C", "M")
+
+# jax.profiler.TraceAnnotation, resolved on the first span
+_annotation_cls = None
+
+
+def _annotation():
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls
+
+
+def span(name: str, **args: Any):
+    """A wall-clock host span on the profiler's clock: use as ``with
+    span("serve.step", step=n) as sp:``.  ``args`` become the event's
+    stats; attach costly ones only when ``sp.is_enabled()``, through
+    ``sp.set_metadata(**more)``."""
+    return _annotation()(name, **args)
+
+
+_gc_open: list = []      # the open serve.gc span, if any
+
+
+def _gc_span(phase: str, info: dict) -> None:
+    if phase == "start":
+        if _annotation().is_enabled():
+            sp = span("serve.gc", generation=info["generation"])
+            sp.__enter__()
+            _gc_open.append(sp)
+    elif _gc_open:
+        _gc_open.pop().__exit__(None, None, None)
+
+
+def install_gc_spans() -> None:
+    """Wrap every garbage-collector pass in a ``serve.gc`` span (arg
+    ``generation``).  Idempotent: one callback per process."""
+    if _gc_span not in gc.callbacks:
+        _annotation()       # resolve before a collection needs it
+        gc.callbacks.append(_gc_span)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,6 +124,9 @@ class _NullTracer:
     def instant(self, name, **kw) -> None: ...
     def complete(self, name, ts, dur, **kw) -> None: ...
     def counter(self, name, values, **kw) -> None: ...
+
+    def span(self, name: str, **args: Any):
+        return span(name, **args)
 
 
 NULL_TRACER = _NullTracer()
@@ -135,6 +191,11 @@ class Tracer:
         self._emit(name, "C", ts, cat=cat, pid=pid, tid=tid,
                    **{k: float(v) for k, v in values.items()})
 
+    def span(self, name: str, **args: Any):
+        """A wall-clock span on the profiler's clock (:func:`span`); it
+        adds nothing to ``events``, which live on the virtual clock."""
+        return span(name, **args)
+
     # -------------------------------------------------------------- exports
     def to_chrome_trace(self) -> dict:
         """Chrome trace-event JSON (Perfetto-loadable).  Virtual token
@@ -171,19 +232,7 @@ class Tracer:
             f.write("\n")
         return doc
 
-    def to_jsonl(self) -> str:
-        lines = []
-        for e in self.events:
-            row = dataclasses.asdict(e)
-            row["args"] = dict(e.args)
-            lines.append(json.dumps(row, sort_keys=True))
-        return "\n".join(lines) + ("\n" if lines else "")
-
     # ------------------------------------------------------------- analysis
-    def request_events(self, rid: int) -> list[Event]:
-        return [e for e in self.events
-                if e.pid == PID_REQUEST and e.tid == rid]
-
     def canonical(self) -> list[tuple]:
         """Deterministic projection (drops ``wall_ts``) — two identical
         seeded runs must compare equal on this."""

@@ -54,7 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.obs.tracing import PID_REQUEST
+from repro.obs.tracing import PID_REQUEST, install_gc_spans
 
 from . import engine as engine_mod
 from .health import HealthMonitor, RequestOutcome, ServeResult, StepReport, nonfinite_slots
@@ -490,6 +490,7 @@ class ContinuousScheduler:
         # one session, one trace: vtime restarts at 0, so a carried-over
         # event buffer would be non-monotone
         self.obs.tracer.reset()
+        install_gc_spans()
 
     def submit(self, req: Request, arrival: float | None = None):
         """Enqueue a request (FIFO admission order).  ``arrival`` pins the
@@ -598,9 +599,12 @@ class ContinuousScheduler:
                 return progressed
         st = self._prefilling
         n = min(self.chunk_tokens, len(st.toks) - st.pos)
-        ok, logits, self._cache = eng.prefill_chunk(
-            self.params, self._cache, st.slot, st.toks, st.pos, n
-        )
+        with self.obs.tracer.span(
+            "serve.prefill_chunk", slot=st.slot, start=st.pos, tokens=n
+        ):
+            ok, logits, self._cache = eng.prefill_chunk(
+                self.params, self._cache, st.slot, st.toks, st.pos, n
+            )
         if not ok:
             # pool dry mid-prefill.  The prefilling request is the youngest
             # admission, so it is its own preemption victim (running
@@ -660,110 +664,139 @@ class ContinuousScheduler:
         resident — with a non-finite-logits watchdog that quarantines
         poisoned slots.  Returns a truthy :class:`StepReport` if any work
         was done — falsy with a non-empty queue means the head can never
-        be admitted (stall)."""
+        be admitted (stall).
+
+        The step and each of its phases run inside a ``serve.*``
+        wall-clock span (DESIGN.md §Observability); while a profile
+        records, ``serve.step`` also carries ``new_programs``, the jitted
+        programs this step compiled or loaded."""
+        with self.obs.tracer.span(
+            "serve.step", step=self.steps, running=len(self.running)
+        ) as sp:
+            before = self.engine.jit_cache_sizes() if sp.is_enabled() else None
+            report = self._step()
+            if before is not None:
+                after = self.engine.jit_cache_sizes()
+                sp.set_metadata(
+                    new_programs=sum(after.values()) - sum(before.values()))
+        return report
+
+    def _step(self) -> StepReport:
+        span = self.obs.tracer.span
         self._step_retired = []
         progressed = False
-        if self.injector is not None:
-            self.injector.on_step_begin(self)
-        progressed |= self._expire_deadlines()
-        progressed |= bool(self._step_retired)  # injected cancels count
-        # pressure cleared? step back up the degradation ladder
-        if self.engine.paged and self.engine.maybe_restore_budget():
-            progressed = True
-        if self.engine.paged and self._cache is not None:
-            # TTL sweep on the virtual clock *before* admission, so blocks
-            # freed by aging are available to this step's admission work
-            swept, self._cache = self.engine.sweep_parked(self._cache)
-            if swept and self.obs.enabled:
-                self.obs.tracer.instant("ttl_sweep", cat="pool", expired=swept)
-                self.obs.metrics.counter(
-                    "pool_ttl_evictions_total",
-                    "parked prefix blocks expired by TTL").inc(swept)
-        if self.chunk_tokens is None:
-            before = (len(self.running), len(self._queue), self.insert_retries)
-            self._cache = self._admit(self._queue, self._cache, self._cur)
-            progressed |= (
-                (len(self.running), len(self._queue), self.insert_retries)
-                != before
-            )
-        else:
-            progressed |= self._chunk_admission_step()
-        if self.engine.paged:
-            # host-tier recalls performed by this step's admission work
-            # charge the virtual clock (far cheaper than the block_size
-            # prefill tokens each recalled block saved)
-            units = self.engine.take_recall_units()
-            if units:
-                self.vtime += units
-                if self.obs.enabled:
-                    self.obs.tracer.instant(
-                        "recall_charge", cat="offload", units=units)
+        with span("serve.housekeeping"):
+            if self.injector is not None:
+                self.injector.on_step_begin(self)
+            progressed |= self._expire_deadlines()
+            progressed |= bool(self._step_retired)  # injected cancels count
+            # pressure cleared? step back up the degradation ladder
+            if self.engine.paged and self.engine.maybe_restore_budget():
+                progressed = True
+            if self.engine.paged and self._cache is not None:
+                # TTL sweep on the virtual clock *before* admission, so
+                # blocks freed by aging are available to this step's
+                # admission work
+                swept, self._cache = self.engine.sweep_parked(self._cache)
+                if swept and self.obs.enabled:
+                    self.obs.tracer.instant("ttl_sweep", cat="pool", expired=swept)
+                    self.obs.metrics.counter(
+                        "pool_ttl_evictions_total",
+                        "parked prefix blocks expired by TTL").inc(swept)
+        with span("serve.admit"):
+            if self.chunk_tokens is None:
+                before = (len(self.running), len(self._queue), self.insert_retries)
+                self._cache = self._admit(self._queue, self._cache, self._cur)
+                progressed |= (
+                    (len(self.running), len(self._queue), self.insert_retries)
+                    != before
+                )
+            else:
+                progressed |= self._chunk_admission_step()
+            if self.engine.paged:
+                # host-tier recalls performed by this step's admission work
+                # charge the virtual clock (far cheaper than the block_size
+                # prefill tokens each recalled block saved)
+                units = self.engine.take_recall_units()
+                if units:
+                    self.vtime += units
+                    if self.obs.enabled:
+                        self.obs.tracer.instant(
+                            "recall_charge", cat="offload", units=units)
         if self.running:
             if self.engine.paged:
-                self._cache = self._ensure_append_capacity(self._queue, self._cache)
+                with span("serve.append_capacity", slots=len(self.running)):
+                    self._cache = self._ensure_append_capacity(
+                        self._queue, self._cache)
                 if not self.running:
                     return StepReport(True, self._step_retired)
-            active_np = np.zeros((self.engine.n_slots,), bool)
-            for s in self.running:
-                active_np[s] = True
-            self._rng, step_rng = jax.random.split(self._rng)
-            nxt, logits, self._cache = self.engine.decode(
-                self.params, jnp.asarray(self._cur), self._cache,
-                active=jnp.asarray(active_np), rng=step_rng,
-            )
-            nxt = np.asarray(nxt)
+            with span("serve.decode_dispatch"):
+                active_np = np.zeros((self.engine.n_slots,), bool)
+                for s in self.running:
+                    active_np[s] = True
+                self._rng, step_rng = jax.random.split(self._rng)
+                nxt, logits, self._cache = self.engine.decode(
+                    self.params, jnp.asarray(self._cur), self._cache,
+                    active=jnp.asarray(active_np), rng=step_rng,
+                )
+            with span("serve.token_sync"):   # the host waits on the device
+                nxt = np.asarray(nxt)
             self.steps += 1
             self.occupancy.append(len(self.running))
             self.vtime += len(self.running)
             if self.watchdog or self.injector is not None:
-                lg = np.asarray(logits)
-                if self.injector is not None:
-                    lg = self.injector.poison_logits(self, lg)
-                if self.watchdog:
-                    for slot in nonfinite_slots(lg, list(self.running)):
-                        # quarantine ONLY the poisoned slot: its sampled
-                        # token is garbage (drawn from non-finite logits),
-                        # so it is discarded with the slot — the rest of
-                        # the batch decodes on untouched
-                        req = self.running.pop(slot)
+                with span("serve.watchdog"):
+                    self._watchdog(logits)
+            with span("serve.retire"):
+                for slot, req in list(self.running.items()):
+                    tok = int(nxt[slot])
+                    req.out.append(tok)
+                    self._step_tokens.append((req.rid, tok))
+                    self._cur[slot] = tok
+                    at_capacity = (
+                        len(req.tokens) + len(req.out) - 1 >= self.engine.capacity
+                    )
+                    if (
+                        len(req.out) >= req.max_new
+                        or (req.eos is not None and tok == req.eos)
+                        or at_capacity
+                    ):
+                        self._retire(req, "finished", slot=slot)
+                        del self.running[slot]
                         self._cache = self._release(self._cache, slot)
-                        reason = (
-                            f"non-finite logits at decode step {self.steps}"
-                        )
-                        self.health.record_event(
-                            "quarantine", slot=slot, rid=req.rid,
-                            reason=reason,
-                        )
-                        if self.obs.enabled:
-                            self.obs.tracer.instant(
-                                "quarantine", cat="health", slot=slot,
-                                rid=req.rid, reason=reason)
-                        self._retire(req, "quarantined", reason, slot=slot)
-            for slot, req in list(self.running.items()):
-                tok = int(nxt[slot])
-                req.out.append(tok)
-                self._step_tokens.append((req.rid, tok))
-                self._cur[slot] = tok
-                at_capacity = (
-                    len(req.tokens) + len(req.out) - 1 >= self.engine.capacity
-                )
-                if (
-                    len(req.out) >= req.max_new
-                    or (req.eos is not None and tok == req.eos)
-                    or at_capacity
-                ):
-                    self._retire(req, "finished", slot=slot)
-                    del self.running[slot]
-                    self._cache = self._release(self._cache, slot)
             progressed = True
             if self.obs.introspector is not None and self.running:
                 self.obs.introspector.probe(
                     self.engine, self._cache, list(self.running), self.steps
                 )
         if self.obs.enabled:
-            self._flush_step_obs()
+            with span("serve.obs_flush"):
+                self._flush_step_obs()
         self.health.maybe_audit(self.engine, self.steps)
         return StepReport(progressed, self._step_retired)
+
+    def _watchdog(self, logits) -> None:
+        """Read the step's logits back and quarantine ONLY the poisoned
+        slots: a poisoned slot's sampled token is garbage (drawn from
+        non-finite logits), so it is discarded with the slot — the rest of
+        the batch decodes on untouched."""
+        lg = np.asarray(logits)
+        if self.injector is not None:
+            lg = self.injector.poison_logits(self, lg)
+        if not self.watchdog:
+            return
+        for slot in nonfinite_slots(lg, list(self.running)):
+            req = self.running.pop(slot)
+            self._cache = self._release(self._cache, slot)
+            reason = f"non-finite logits at decode step {self.steps}"
+            self.health.record_event(
+                "quarantine", slot=slot, rid=req.rid, reason=reason,
+            )
+            if self.obs.enabled:
+                self.obs.tracer.instant(
+                    "quarantine", cat="health", slot=slot, rid=req.rid,
+                    reason=reason)
+            self._retire(req, "quarantined", reason, slot=slot)
 
     def _flush_step_obs(self) -> None:
         """End-of-step observability flush: stamp the step's buffered

@@ -152,10 +152,36 @@ def test_chrome_export_validates_and_roundtrips(tmp_path):
     back = load_trace_events(doc)
     assert [(e.name, e.ph, e.ts, e.pid, e.tid, e.dur) for e in back] == [
         (e.name, e.ph, e.ts, e.pid, e.tid, e.dur) for e in tr.events]
-    # jsonl: one parseable row per event
-    lines = tr.to_jsonl().strip().split("\n")
-    assert len(lines) == len(tr.events)
-    assert json.loads(lines[0])["name"] == "submitted"
+
+
+def test_span_is_a_profiler_annotation_and_adds_no_event():
+    """``tracer.span`` is a wall-clock span on the profiler's clock, the
+    same on the enabled and the null tracer; the virtual-clock event
+    buffer never sees it."""
+    from repro.obs.tracing import NULL_TRACER
+
+    tr = Tracer()
+    for t in (tr, NULL_TRACER):
+        with t.span("serve.step", step=3) as sp:
+            assert isinstance(sp, jax.profiler.TraceAnnotation)
+    assert tr.events == []
+
+
+def test_obs_imports_without_jax():
+    """The stdlib-only tools (tools/obs_report.py, tools/check_bench_
+    regression.py) import repro.obs on machines without JAX: the spans
+    load it only when one is opened."""
+    code = (
+        "import sys; sys.modules['jax'] = None\n"
+        "from repro.obs import Observability, Tracer\n"
+        "obs = Observability(); obs.tracer.instant('x')\n"
+        "assert len(obs.tracer.events) == 1 and 'jax' not in "
+        "[m for m in sys.modules if sys.modules[m] is not None]\n"
+    )
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, timeout=120)
+    assert r.returncode == 0, r.stderr
 
 
 def test_validate_catches_malformed():
@@ -230,6 +256,21 @@ def test_disabled_obs_identical_outputs_and_no_extra_compiles(setup):
     assert jits_off == jits_on, (jits_off, jits_on)
     # and the disabled path really recorded nothing
     assert isinstance(jits_off, dict) and sum(jits_off.values()) > 0
+
+
+def test_decode_program_keeps_the_name_the_trace_readers_match(setup):
+    """The device trace names each execution of the batched decode step
+    by its module, ``jit__decode_active_impl``; the benchmark's
+    ``decode_step_ms`` reader matches ``decode_active_impl``."""
+    import jax.numpy as jnp
+
+    cfg, mk, slab, params = setup
+    eng = Engine(mk(pool_blocks=24), n_slots=2, capacity=64)
+    lowered = eng._decode_active.lower(
+        params, jnp.zeros((2,), jnp.int32), eng.new_cache(),
+        jnp.ones((2,), bool))
+    name = str(lowered.compiler_ir().operation.attributes["sym_name"])
+    assert "decode_active_impl" in name, name
 
 
 def test_trace_determinism_two_seeded_runs(setup):

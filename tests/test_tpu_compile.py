@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +67,15 @@ def _custom_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+def _kernel_ops(compiled) -> set[str]:
+    """The Mosaic custom calls' instruction names less their ``.<n>``: the
+    names the device trace gives the kernels' ops, which the benchmark's
+    roofline readers match (``%paged_fused_retrieve_hm.10``)."""
+    return {m.rsplit(".", 1)[0] for m in re.findall(
+        r'^\s*%([\w.-]+) = .*custom_call_target="tpu_custom_call"',
+        compiled.as_text(), re.M)}
+
+
 @pytest.mark.parametrize("capacity,budget", POINTS)
 def test_paged_retrieve_compiles(chip, capacity, budget):
     nb = capacity // BLOCK
@@ -85,7 +95,9 @@ def test_paged_retrieve_compiles(chip, capacity, budget):
             block_size=BLOCK, sink=4, recent=64, interpret=False,
         )
 
-    assert _custom_calls(jax.jit(f).lower(*args).compile()) == 1
+    compiled = jax.jit(f).lower(*args).compile()
+    assert _custom_calls(compiled) == 1
+    assert _kernel_ops(compiled) == {"paged_fused_retrieve_hm"}
 
 
 @pytest.mark.parametrize("capacity,budget", POINTS)
@@ -107,7 +119,9 @@ def test_paged_attend_compiles(chip, capacity, budget):
             blk_k=ops.ATTEND_BLK, interpret=False,
         )
 
-    assert _custom_calls(jax.jit(f).lower(*args).compile()) == 1
+    compiled = jax.jit(f).lower(*args).compile()
+    assert _custom_calls(compiled) == 1
+    assert _kernel_ops(compiled) == {"paged_fused_sparse_attention_hm"}
 
 
 def test_decode_step_compiles_and_fits(chip, monkeypatch):
@@ -142,6 +156,8 @@ def test_decode_step_compiles_and_fits(chip, monkeypatch):
             f"jit({name})" in ln and "tpu_custom_call" in ln
             for ln in txt.splitlines()
         ), name
+    assert {"paged_fused_retrieve_hm",
+            "paged_fused_sparse_attention_hm"} <= _kernel_ops(compiled)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
