@@ -20,38 +20,44 @@ This kernel fuses the whole retrieval stage into one ``pallas_call``:
     scores), group-reduced over the query group (``max``/``sum``) and
     masked (``length``/``sink``/``recent``) in-register;
   * the masked block scores are reinterpreted as monotone uint32 keys
-    (``topk_select``'s trick: float order == unsigned order) and drive an
-    exact radix-histogram search for τ, the budget-th largest key —
-    ``NPASS`` = 4 sweeps over the code blocks, each accumulating a
-    256-bucket histogram of the next 8 key bits among the keys matching
-    the prefix found so far;
-  * a final sweep re-scores the blocks and compacts the selected indices
-    { key > τ } ∪ first (budget − m) ties in ascending position order —
-    the same index *set* ``lax.top_k`` returns on the same scores.
+    (``topk_select``'s trick: float order == unsigned order) and stored
+    in a VMEM key row, block i in row i — the one sweep over HBM;
+  * τ, the budget-th largest key, is found exactly by 32 counting passes
+    over the VMEM row (one bit of τ each, from the top), and m = the
+    strictly-greater count by one more;
+  * a compaction pass over the VMEM row, a lane-width of tokens a step,
+    places the selected indices { key > τ } ∪ first (budget − m) ties in
+    ascending position order — the same index *set* ``lax.top_k`` returns
+    on the same scores.
 
 Per-token state in HBM: none.  The score tensors simply never exist as
-arrays — each block's scores live in VREGs for the duration of one fold
-step.  The only outputs are the index set ``[BH, budget]`` and the
+arrays — each block's scores live in VREGs until its keys are stored in
+VMEM.  The only outputs are the index set ``[BH, budget]`` and the
 (lane-padded) τ/m scalars.
 
-Cost: NPASS + 1 = 5 streaming sweeps over the packed codes.  The codes
-are 1/16 of the bf16 key bytes (Eq. 8), so five sweeps ≈ 0.31× the key
-bytes — still far below the 2·4·Hq·S score-tensor round trip the fusion
-removes (at Hq = 32, D = 128: score round trip ≈ 256·S bytes vs
-5·codes = 80·S bytes per batch row, and the gap widens with Hq).
+Cost: one streaming sweep over the packed codes, 1/16 of the bf16 key
+bytes (Eq. 8) plus the scale/zero side-car — far below the 2·4·Hq·S
+score-tensor round trip the fusion removes (at Hq = 32, D = 128: score
+round trip ≈ 256·S bytes vs codes = 16·S bytes per batch row).  The τ
+search and the compaction read VMEM only.
 
-VMEM per step: 2 double-buffer slots of (codes + scale + zero) block ≈
-2·(blk_s·D/8 + 2·(blk_s/g)·D·2) bytes — 48 KiB at blk_s = 512, D = 128,
-g = 32 — plus the [1, budget] index block.  Grid: (B·Hkv,).
+VMEM per program: 2 double-buffer slots of the (codes + scale + zero)
+block; the key row, S·4 B when the block is a multiple of 128 tokens
+(slab, blk_s = 512) and S/bs·512 B otherwise (paged, rows padded to 128
+lanes: 192 KiB at S = 12288, bs = 32; 512 KiB at S = 32768); and the
+[1, budget] rank scratch, 32 KiB at budget 1024.  A trace-time check
+holds the sum under the scoped VMEM limit.  Grid: (B·Hkv,) for the
+slab, (B, Hkv) for the paged kernel.
 
-Mosaic notes (CPU CI interprets the exact kernel code): the histogram,
-the in-block prefix counts and the rank placement of the compaction are
-masked reductions over iota comparisons — no sort, cumsum or scatter,
-none of which Mosaic lowers (DESIGN.md §Chip layouts).
+Mosaic notes (CPU CI interprets the exact kernel code): counts are
+reductions, in-block prefix counts a 0/1 matmul, the rank placement
+masked reductions over iota comparisons in a 128-aligned window — no
+sort, cumsum, reshape or scatter (DESIGN.md §Chip layouts).
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -61,25 +67,13 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.retrieval import NEG_INF
 
 from .fier_score import score_block
-from .topk_select import LANE, _sortable_keys, _unsortable
+from .topk_select import LANE, _sortable_keys, _unsortable, kth_largest_key
 
-NPASS = 4    # radix-histogram passes: 8 bits of the uint32 keys per pass
-RADIX = 256  # buckets per pass
+VMEM_LIMIT_BYTES = 16 * 2**20   # Mosaic's default scoped VMEM limit on a v5e
 
 
 def _iota(shape, dim):
     return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
-
-
-def _to_col(x: jax.Array) -> jax.Array:
-    """Exact [1, n] → [n, 1] int32 transpose as a masked lane reduction
-    (each output sums one value and n − 1 zeros).  Mosaic has no general
-    small-shape transpose; this is n² VPU work, trivial at block size."""
-    n = x.shape[1]
-    return jnp.sum(
-        jnp.where(_iota((n, n), 0) == _iota((n, n), 1), x, 0),
-        axis=1, keepdims=True,
-    )
 
 
 def head_rows(x: jax.Array, h) -> jax.Array:
@@ -95,124 +89,93 @@ def head_rows(x: jax.Array, h) -> jax.Array:
     return jnp.sum(jnp.where(sel, x, 0), axis=1)
 
 
-def _threshold_select(sweep, budget: int, idx_ref, tau_ref, m_ref):
-    """Radix-histogram τ search + tie-aware index compaction over a
-    ``sweep(fold, init)`` abstraction that folds over scored blocks
-    ``(keys_row [1, n], keys_col [n, 1], pos_col [n, 1])``.
+def _threshold_select(keys_ref, out_v, budget: int, idx_ref, tau_ref, m_ref):
+    """Exact τ search + tie-aware index compaction over the VMEM key row.
 
-    Shared verbatim by the contiguous (slab) and the page-table-aware
-    retrieval kernels: both produce per-block monotone-uint32 keys of the
-    masked kv scores; only the *addressing* of the code stream differs.
-    Writes the selected index set, τ, and the strictly-greater count to
-    the (lane-padded) output refs.
+    keys_ref [nb, n] uint32 holds the monotone keys of the masked kv
+    scores, block i's n keys in row i: position i·n + o sits at [i, o].
+    out_v [1, budget rounded up to LANE] int32 is scratch.  Shared
+    verbatim by the contiguous (slab) and the page-table-aware retrieval
+    kernels: both write their one sweep's keys here; only the
+    *addressing* of the code stream differs.  Writes the selected index
+    set, τ, and the strictly-greater count to the (lane-padded) output
+    refs.
 
-    Written for Mosaic: no cumsum, reversal or scatter.  Prefix counts
-    and the rank scatter are masked reductions over iota comparisons, and
-    the loop carries are [1, 1] vectors.
+    Written for Mosaic: no sort, cumsum, reshape or scatter.  Counts are
+    reductions over the row, in-block prefix counts a 0/1 matmul (exact:
+    bf16 ones, f32 sums), and the rank placement masked reductions over
+    iota comparisons.
     """
-    # ---- phase 1: radix-histogram search for τ (the budget-th largest key)
-    def radix_pass(p, carry):
-        t, remaining, greater = carry                       # [1, 1] each
-        pw = p.astype(jnp.uint32)
-        shift = jnp.uint32(24) - jnp.uint32(8) * pw
-        # participation: keys matching the 8p prefix bits found so far
-        # (p = 0: everyone; the clamp keeps the dead branch's shift < 32)
-        himask = jnp.where(
-            p == 0,
-            jnp.uint32(0),
-            jnp.uint32(0xFFFFFFFF)
-            << jnp.minimum(jnp.uint32(32) - jnp.uint32(8) * pw, jnp.uint32(31)),
-        )
+    nb, n = keys_ref.shape
 
-        def fold(blk, hist):
-            _, keys, _ = blk                                # keys [n, 1]
-            n = keys.shape[0]
-            part = (keys & himask) == t
-            digit = ((keys >> shift) & jnp.uint32(0xFF)).astype(jnp.int32)
-            onehot = (digit == _iota((n, RADIX), 1)) & part  # [n, RADIX]
-            return hist + jnp.sum(
-                onehot.astype(jnp.int32), axis=0, keepdims=True
-            )
+    def count(pred):
+        return jnp.sum(pred(keys_ref[...]).astype(jnp.int32))
 
-        hist = sweep(fold, jnp.zeros((1, RADIX), jnp.int32))  # [1, RADIX]
-        # ge[j] = count(digit ≥ j), bucket j on sublanes
-        ge = jnp.sum(
-            jnp.where(
-                _iota((RADIX, RADIX), 1) >= _iota((RADIX, RADIX), 0), hist, 0
-            ),
-            axis=1, keepdims=True,
-        )                                                   # [RADIX, 1]
-        # τ's digit: the highest bucket where the ≥-count reaches `remaining`
-        jstar = jnp.max(
-            jnp.where(ge >= remaining, _iota((RADIX, 1), 0), -1),
-            axis=0, keepdims=True,
-        )                                                   # [1, 1]
-        above = jnp.sum(
-            jnp.where(_iota((1, RADIX), 1) > jstar, hist, 0),
-            axis=1, keepdims=True,
-        )
-        t = t | (jstar.astype(jnp.uint32) << shift)
-        return t, remaining - above, greater + above
+    # ---- phase 1: τ, the budget-th largest key, and m = |{ key > τ }|
+    tau = kth_largest_key(lambda c: count(lambda k: k >= c), budget)
+    m = count(lambda k: k > tau)
 
-    tau_key, _, m = jax.lax.fori_loop(
-        0, NPASS, radix_pass,
-        (
-            jnp.zeros((1, 1), jnp.uint32),
-            jnp.full((1, 1), budget, jnp.int32),
-            jnp.zeros((1, 1), jnp.int32),
-        ),
-    )
-    # m = |{ key > τ }| exactly: every strictly-greater key is counted at
-    # the first radix pass where its digit exceeds τ's (it matches the
-    # prefix up to that pass), and never again after it stops matching.
+    # ---- phase 2: compact { key > τ } at ranks [0, m) and the first
+    # (budget − m) ties at [m, budget), each in ascending position order.
+    # A step takes `tile` whole blocks, at most a lane-width of tokens, so
+    # one class's ranks in a step lie in one `win`-lane window of out_v.
+    tile = math.gcd(nb, max(1, LANE // n))
+    wpad = out_v.shape[1]
+    win = min(wpad, -(-(tile * n) // LANE) * LANE + LANE)
+    upto = (_iota((n, n), 1) <= _iota((n, n), 0)).astype(jnp.bfloat16)
+    before = _iota((tile, tile), 0) < _iota((tile, tile), 1)   # [r', r]
+    out_v[...] = jnp.zeros(out_v.shape, jnp.int32)
 
-    # ---- phase 2: re-score and compact { key > τ } ∪ first (budget−m) ties
-    def compact_fold(blk, carry):
-        ngt, ntie, out = carry
-        keys_row, keys_col, pos_col = blk
-        n = keys_row.shape[1]
-        gt_row = (keys_row > tau_key).astype(jnp.int32)     # [1, n]
-        tie_row = (keys_row == tau_key).astype(jnp.int32)
-        gt = keys_col > tau_key                             # [n, 1]
-        tie = keys_col == tau_key
-        # inclusive in-block prefix counts: row i sums tokens t ≤ i
-        upto = _iota((n, n), 1) <= _iota((n, n), 0)
-        cgt = jnp.sum(jnp.where(upto, gt_row, 0), axis=1, keepdims=True)
-        ctie = jnp.sum(jnp.where(upto, tie_row, 0), axis=1, keepdims=True)
-        take_tie = tie & (ntie + ctie <= budget - m)
-        # rank of each selected token: >τ fill [0, m) in ascending
-        # position, taken ties fill [m, budget); −1 = not selected
-        dest = jnp.where(
-            gt, ngt + cgt - 1, jnp.where(take_tie, m + ntie + ctie - 1, -1)
-        )                                                   # [n, 1]
-        out = out + jnp.sum(
-            jnp.where(dest == _iota((n, budget), 1), pos_col, 0),
-            axis=0, keepdims=True,
-        )                                                   # [1, budget]
-        return (
-            ngt + jnp.sum(gt_row, axis=1, keepdims=True),
-            ntie + jnp.sum(tie_row, axis=1, keepdims=True),
-            out,
-        )
+    def ranks(sel_rows, sel_cols):
+        """Inclusive count of selected tokens up to each token of the step,
+        in position order ([n, tile], block r in column r), and the total."""
+        tot = jnp.sum(sel_rows.astype(jnp.int32), axis=1, keepdims=True)
+        off = jnp.sum(jnp.where(before, tot, 0), axis=0, keepdims=True)
+        incl = jnp.dot(
+            upto, sel_cols.astype(jnp.bfloat16),
+            preferred_element_type=jnp.float32,
+        ).astype(jnp.int32)
+        return incl + off, jnp.sum(tot)
 
-    _, _, out = sweep(
-        compact_fold,
-        (
-            jnp.zeros((1, 1), jnp.int32),
-            jnp.zeros((1, 1), jnp.int32),
-            jnp.zeros((1, budget), jnp.int32),
-        ),
-    )
-    idx_ref[...] = out.reshape(idx_ref.shape)
-    tau_ref[...] = jnp.broadcast_to(_unsortable(tau_key), tau_ref.shape)
+    def place(first, sel, rank, total, pos):
+        """out[first + rank − 1] = pos for the selected tokens of a step."""
+        @pl.when(total > 0)
+        def _():
+            w = pl.multiple_of(jnp.minimum(first // LANE * LANE, wpad - win), LANE)
+            dest = jnp.where(sel, first - w + rank - 1, -1)          # [n, tile]
+            acc = out_v[:, pl.ds(w, win)]
+            for r in range(tile):
+                acc = acc + jnp.sum(
+                    jnp.where(dest[:, r:r + 1] == _iota((n, win), 1),
+                              pos[:, r:r + 1], 0),
+                    axis=0, keepdims=True,
+                )
+            out_v[:, pl.ds(w, win)] = acc
+
+    def compact_step(j, carry):
+        ngt, ntie = carry
+        rows = keys_ref[pl.ds(pl.multiple_of(j * tile, tile), tile), :]
+        cols = jax.lax.bitcast_convert_type(
+            jax.lax.bitcast_convert_type(rows, jnp.int32).T, jnp.uint32
+        )                                                   # [n, tile]
+        pos = (j * tile + _iota((n, tile), 1)) * n + _iota((n, tile), 0)
+        gt, tie = cols > tau, cols == tau
+        cgt, gt_total = ranks(rows > tau, gt)
+        ctie, tie_total = ranks(rows == tau, tie)
+        place(ngt, gt, cgt, gt_total, pos)
+        place(m + ntie, tie & (ntie + ctie <= budget - m), ctie, tie_total, pos)
+        return ngt + gt_total, ntie + tie_total
+
+    jax.lax.fori_loop(0, nb // tile, compact_step, (jnp.int32(0), jnp.int32(0)))
+    idx_ref[...] = out_v[:, :budget].reshape(idx_ref.shape)
+    tau_ref[...] = _unsortable(jnp.broadcast_to(tau, tau_ref.shape))
     m_ref[...] = jnp.broadcast_to(m, m_ref.shape)
 
 
 def _masked_block_keys(s, i, blk_s, length, sink, recent, group_reduce):
     """Group-reduce + mask one scored block and lift to monotone keys.
 
-    s [rep, blk_s] f32 (VREG-resident scores) → (keys uint32 [1, blk_s],
-    the same keys as a column [blk_s, 1], positions int32 [blk_s, 1]).
+    s [rep, blk_s] f32 (VREG-resident scores) → keys uint32 [1, blk_s].
     Shared by the slab and paged kernels so the masking arithmetic is
     identical bit for bit.
     """
@@ -227,19 +190,55 @@ def _masked_block_keys(s, i, blk_s, length, sink, recent, group_reduce):
     if recent > 0:
         is_recent = (pos >= length - recent) & (pos < length)
         kv = jnp.where(is_recent, jnp.inf, kv)
-    keys = _sortable_keys(kv)
-    keys_col = jax.lax.bitcast_convert_type(
-        _to_col(jax.lax.bitcast_convert_type(keys, jnp.int32)), jnp.uint32
-    )
-    return keys, keys_col, i * blk_s + _iota((blk_s, 1), 0)
+    return _sortable_keys(kv)
+
+
+def _sweep_keys(keys_ref, start_block, wait_block, block_keys):
+    """The one HBM sweep: block i is waited for, scored, and its keys
+    stored in row i of the VMEM key row, with block i + 1's copies in
+    flight meanwhile.  Scores exist only in VREGs, one block at a time."""
+    nb = keys_ref.shape[0]
+    start_block(0)
+
+    def body(i, carry):
+        @pl.when(i + 1 < nb)
+        def _prefetch():
+            start_block(i + 1)
+
+        wait_block(i)
+        keys_ref[pl.ds(i, 1), :] = block_keys(i)
+        return carry
+
+    jax.lax.fori_loop(0, nb, body, 0)
+
+
+def _padded_bytes(shape, dtype) -> int:
+    """VMEM bytes of a buffer: its two minor dims padded to Mosaic's
+    (sublane, 128)-tile of the dtype (8 rows at 32 bits, 16 at 16, 32 at 8)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    *major, rows, lanes = shape
+    sub = 8 * 4 // itemsize
+    rows, lanes = -(-rows // sub) * sub, -(-lanes // LANE) * LANE
+    return math.prod(major) * rows * lanes * itemsize
+
+
+def _check_vmem(S: int, scratch) -> None:
+    """Trace-time guard: the DMA double buffers, the key row and the rank
+    scratch must fit the scoped VMEM limit."""
+    need = sum(_padded_bytes(sh, dt) for sh, dt in scratch)
+    if need > VMEM_LIMIT_BYTES:
+        raise ValueError(
+            f"one-pass retrieval at S={S}: its VMEM scratch takes {need} B, "
+            f"over the {VMEM_LIMIT_BYTES} B scoped limit"
+        )
 
 
 def _kernel(
     len_ref, q_ref, codes_hbm, scale_hbm, zero_hbm,
     idx_ref, tau_ref, m_ref,
-    codes_v, scale_v, zero_v, sems, *,
+    codes_v, scale_v, zero_v, keys_v, out_v, sems, *,
     budget: int, group: int, blk_s: int, group_reduce: str,
-    sink: int, recent: int, S: int,
+    sink: int, recent: int,
 ):
     """One (batch·kv-head) row of one-pass retrieval.
 
@@ -247,10 +246,10 @@ def _kernel(
     head-major slabs [BH, S/8|S/g, D] in ANY space (DMA'd blockwise);
     idx_ref [1, budget] int32; tau_ref [1, LANE] f32; m_ref [1, LANE]
     int32; codes_v/scale_v/zero_v: [2, ...] double-buffer scratch;
-    sems [2, 3] DMA semaphores (slot × operand).
+    keys_v [S/blk_s, blk_s] uint32, the row's keys; out_v the
+    compaction's rank scratch; sems [2, 3] DMA semaphores (slot × operand).
     """
     b = pl.program_id(0)
-    nb = S // blk_s
     n8 = blk_s // 8
     ng = blk_s // group
     length = len_ref[0, 0]
@@ -282,33 +281,15 @@ def _kernel(
             cp.wait()
 
     def block_keys(i):
-        """Monotone-uint32 keys of block i's masked kv scores (row and
-        column) and their positions.
-
-        Scores exist only here, in VREGs, for the duration of one fold.
-        """
+        """Monotone-uint32 keys [1, blk_s] of block i's masked kv scores."""
         slot = jax.lax.rem(i, 2)
         s = score_block(
             qbf, codes_v[slot], scale_v[slot], zero_v[slot], group=group
         )                                                   # [rep, blk_s]
         return _masked_block_keys(s, i, blk_s, length, sink, recent, group_reduce)
 
-    def sweep(fold, init):
-        """fold(keys, pos, carry) over all code blocks, next block's DMA
-        in flight while the current block is scored."""
-        start_block(0)
-
-        def body(i, carry):
-            @pl.when(i + 1 < nb)
-            def _prefetch():
-                start_block(i + 1)
-
-            wait_block(i)
-            return fold(block_keys(i), carry)
-
-        return jax.lax.fori_loop(0, nb, body, init)
-
-    _threshold_select(sweep, budget, idx_ref, tau_ref, m_ref)
+    _sweep_keys(keys_v, start_block, wait_block, block_keys)
+    _threshold_select(keys_v, out_v, budget, idx_ref, tau_ref, m_ref)
 
 
 @functools.partial(
@@ -350,10 +331,18 @@ def fused_retrieve_hm(
     while S % blk:
         blk //= 2
     assert blk % 8 == 0 and blk % group == 0, (blk, group)
+    scratch = [
+        ((2, blk // 8, D), jnp.uint8),
+        ((2, blk // group, D), scale.dtype),
+        ((2, blk // group, D), zero.dtype),
+        ((S // blk, blk), jnp.uint32),
+        ((1, -(-budget // LANE) * LANE), jnp.int32),
+    ]
+    _check_vmem(S, scratch)
     idx, tau, m = pl.pallas_call(
         functools.partial(
             _kernel, budget=budget, group=group, blk_s=blk,
-            group_reduce=group_reduce, sink=sink, recent=recent, S=S,
+            group_reduce=group_reduce, sink=sink, recent=recent,
         ),
         grid=(BH,),
         in_specs=[
@@ -376,9 +365,7 @@ def fused_retrieve_hm(
             jax.ShapeDtypeStruct((BH, 1, LANE), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, blk // 8, D), jnp.uint8),
-            pltpu.VMEM((2, blk // group, D), scale.dtype),
-            pltpu.VMEM((2, blk // group, D), zero.dtype),
+            *(pltpu.VMEM(sh, dt) for sh, dt in scratch),
             pltpu.SemaphoreType.DMA((2, 3)),
         ],
         interpret=interpret,
@@ -391,17 +378,18 @@ def fused_retrieve_hm(
 def _paged_kernel(
     bt_ref, len_ref, q_ref, codes_hbm, scale_hbm, zero_hbm,
     idx_ref, tau_ref, m_ref,
-    codes_v, scale_v, zero_v, sems, *,
+    codes_v, scale_v, zero_v, keys_v, out_v, sems, *,
     budget: int, group: int, block_size: int, group_reduce: str,
-    sink: int, recent: int, n_btab: int,
+    sink: int, recent: int,
 ):
     """One (batch, kv-head) row of one-pass retrieval over a *paged* pool.
 
     bt_ref [1, n_btab] int32 (SMEM) — this request's block table row;
     len_ref [1, 1] int32 (SMEM); q_ref [rep, D]; codes/scale/zero: whole
     paged side-car pools [N, bs/8|bs/g, Hkv, D] in ANY space; outputs as
-    in the contiguous kernel; scratch holds every kv head of a block
-    (``head_rows`` picks this row's head).  The per-row DMA stream walks
+    in the contiguous kernel; the DMA scratch holds every kv head of a
+    block (``head_rows`` picks this row's head); keys_v [n_btab, bs]
+    uint32 holds the row's keys, out_v the compaction's ranks.  The per-row DMA stream walks
     ``block_table[b]`` instead of a contiguous slab: logical code block
     ``i`` is fetched from pool row ``bt[i]`` (unallocated entries point
     at the null block, whose garbage scores are masked by ``length``).
@@ -446,20 +434,8 @@ def _paged_kernel(
         )                                                   # [rep, bs]
         return _masked_block_keys(s, i, bs, length, sink, recent, group_reduce)
 
-    def sweep(fold, init):
-        start_block(0)
-
-        def body(i, carry):
-            @pl.when(i + 1 < n_btab)
-            def _prefetch():
-                start_block(i + 1)
-
-            wait_block(i)
-            return fold(block_keys(i), carry)
-
-        return jax.lax.fori_loop(0, n_btab, body, init)
-
-    _threshold_select(sweep, budget, idx_ref, tau_ref, m_ref)
+    _sweep_keys(keys_v, start_block, wait_block, block_keys)
+    _threshold_select(keys_v, out_v, budget, idx_ref, tau_ref, m_ref)
 
 
 @functools.partial(
@@ -504,10 +480,18 @@ def paged_fused_retrieve_hm(
     assert codes.shape[1] * 8 == block_size, (codes.shape, block_size)
     if group_reduce not in ("max", "sum"):
         raise ValueError(f"unknown group reduction {group_reduce!r}")
+    scratch = [
+        ((2, block_size // 8, Hkv, D), jnp.uint8),
+        ((2, block_size // group, Hkv, D), scale.dtype),
+        ((2, block_size // group, Hkv, D), zero.dtype),
+        ((n_btab, block_size), jnp.uint32),
+        ((1, -(-budget // LANE) * LANE), jnp.int32),
+    ]
+    _check_vmem(S, scratch)
     idx, tau, m = pl.pallas_call(
         functools.partial(
             _paged_kernel, budget=budget, group=group, block_size=block_size,
-            group_reduce=group_reduce, sink=sink, recent=recent, n_btab=n_btab,
+            group_reduce=group_reduce, sink=sink, recent=recent,
         ),
         grid=(B, Hkv),
         in_specs=[
@@ -534,9 +518,7 @@ def paged_fused_retrieve_hm(
             jax.ShapeDtypeStruct((B, Hkv, 1, LANE), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, block_size // 8, Hkv, D), jnp.uint8),
-            pltpu.VMEM((2, block_size // group, Hkv, D), scale.dtype),
-            pltpu.VMEM((2, block_size // group, Hkv, D), zero.dtype),
+            *(pltpu.VMEM(sh, dt) for sh, dt in scratch),
             pltpu.SemaphoreType.DMA((2, 3)),
         ],
         interpret=interpret,

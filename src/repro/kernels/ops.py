@@ -151,9 +151,10 @@ def retrieve(
     [N, bs/8, Hkv, D] walked through ``view.block_table`` in-kernel) →
     idx int32 [B, Hkv, budget], the same index set as ``select_topk``
     over the masked, group-reduced ``fier_score`` scores.  One Pallas
-    kernel streams the codes, scores each block in VREGs, group-reduces
-    and masks in-register, radix-searches τ and compacts — neither the
-    [B,Hq,S] nor the [B,Hkv,S] score tensor ever exists as an array.
+    kernel streams the codes once, scores each block in VREGs,
+    group-reduces and masks in-register, keeps the row's keys in VMEM,
+    searches τ and compacts there — neither the [B,Hq,S] nor the
+    [B,Hkv,S] score tensor ever exists in HBM.
     ``return_stats=True`` additionally returns (tau f32 [B,Hkv],
     m int32 [B,Hkv]) — the budget-th score and the strictly-greater
     count per row.

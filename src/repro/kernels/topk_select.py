@@ -50,6 +50,17 @@ def _unsortable(key: jax.Array) -> jax.Array:
     return jax.lax.bitcast_convert_type(u, jnp.float32)
 
 
+def kth_largest_key(count_ge, budget: int) -> jax.Array:
+    """The ``budget``-th largest key, given ``count_ge(c)`` = |{ key ≥ c }|:
+    the largest t with count_ge(t) ≥ budget, found one bit at a time from
+    the top (32 counting passes).  Shared with the one-pass kernels."""
+    def bit_step(i, t):
+        cand = t | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        return jnp.where(count_ge(cand) >= budget, cand, t)
+
+    return jax.lax.fori_loop(0, 32, bit_step, jnp.uint32(0))
+
+
 def _kernel(s_ref, tau_ref, m_ref, keys_ref, *, budget: int, blk_s: int):
     """One (batch·kv-head) row: radix binary search for the budget-th key.
 
@@ -68,11 +79,7 @@ def _kernel(s_ref, tau_ref, m_ref, keys_ref, *, budget: int, blk_s: int):
 
         return jax.lax.fori_loop(0, nb, blk, jnp.int32(0))
 
-    def bit_step(i, t):
-        cand = t | (jnp.uint32(1) << jnp.uint32(31 - i))
-        return jnp.where(count_ge(cand) >= budget, cand, t)
-
-    t = jax.lax.fori_loop(0, 32, bit_step, jnp.uint32(0))
+    t = kth_largest_key(count_ge, budget)
     # t is the largest key with count(>= t) >= budget ⇒ exactly the
     # budget-th largest key;  m = strictly-greater count = count(>= t+1).
     m = count_ge(t + jnp.uint32(1))

@@ -169,6 +169,57 @@ def test_paged_retrieve_exact_vs_slab(B, S, Hkv, Hq, D, g, bs):
         )
 
 
+@pytest.mark.parametrize("B,S,Hkv,Hq,D,g,bs,lengths,budget", [
+    (3, 256, 2, 4, 64, 32, 32, (37, 200, 129), 64),
+    (2, 192, 3, 6, 16, 8, 24, (5, 100), 120),
+    (1, 512, 1, 8, 128, 32, 64, (300,), 400),
+])
+def test_paged_retrieve_ragged_tail_vs_slab(B, S, Hkv, Hq, D, g, bs, lengths, budget):
+    """Per-slot lengths that end mid-block, table entries past each length
+    on the null block, and budget > length for some slots: the masked
+    tail of the kernel's key row selects the slab kernel's exact indices
+    (order included), τ and m."""
+    q, K, V, qk, k_pool, v_pool, meta, table = _paged_inputs(
+        B, S, Hkv, Hq, D, g, bs, seed=2
+    )
+    length = jnp.asarray(lengths, jnp.int32)
+    used = -(-length // bs)
+    table = jnp.where(jnp.arange(S // bs)[None] < used[:, None], table, 0)
+    kw = dict(sink=4, recent=8, return_stats=True)
+    slab = ops.retrieve(q, CacheView.slab(None, None, qk, length), budget, **kw)
+    pview = CacheView.paged(None, None, meta, table, length)
+    got = ops.retrieve(q, pview, budget, **kw)
+    for want_part, got_part in zip(slab, got):
+        np.testing.assert_array_equal(np.asarray(want_part), np.asarray(got_part))
+    want = ref.retrieve(q, pview, budget, sink=4, recent=8)
+    np.testing.assert_array_equal(
+        np.sort(np.asarray(got[0]), -1), np.sort(np.asarray(want), -1)
+    )
+
+
+def test_paged_retrieve_vmem_guard_names_S():
+    """A key row over the scoped VMEM limit is refused while tracing, with
+    the context length in the message (2**22 tokens at block 32: 64 MiB
+    of 128-lane rows)."""
+    from repro.kernels import fused_retrieval as fr
+
+    bs, n_btab = 32, 2**17
+    sds = jax.ShapeDtypeStruct
+    args = (
+        sds((1, 1, 1, 128), jnp.float32),
+        sds((2, bs // 8, 1, 128), jnp.uint8),
+        sds((2, 1, 1, 128), jnp.bfloat16),
+        sds((2, 1, 1, 128), jnp.bfloat16),
+        sds((1, n_btab), jnp.int32),
+        sds((1,), jnp.int32),
+    )
+    with pytest.raises(ValueError, match=f"S={bs * n_btab}"):
+        jax.eval_shape(
+            lambda *a: fr.paged_fused_retrieve_hm(*a, 64, group=32, block_size=bs),
+            *args,
+        )
+
+
 @pytest.mark.parametrize("B,S,Hkv,Hq,D,g,bs", PAGED_SHAPES)
 def test_paged_decode_bit_identical_vs_slab(B, S, Hkv, Hq, D, g, bs):
     """Paged one-pass decode (retrieval + select-and-attend, block table

@@ -31,9 +31,10 @@ CFG = get_config("olmo-1b")
 HKV, D = CFG.n_kv_heads, CFG.d_head
 REP = CFG.n_heads // CFG.n_kv_heads
 SLOTS, BLOCK, GROUP = 4, 32, 32
-# (pool capacity per slot, FIER budget): the chip smoke run's point and a
-# 32k context at the same ~12% budget
-POINTS = [(8192, 1024), (32768, 4096)]
+# (pool capacity per slot, FIER budget): the chip smoke run's point, a
+# 32k context at the same ~12% budget, and the benchmark cell
+# olmo-1b.longctx_decode's point
+POINTS = [(8192, 1024), (32768, 4096), (12288, 1024)]
 # one v5e chip's HBM
 HBM_BYTES = 16e9
 
