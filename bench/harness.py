@@ -8,6 +8,8 @@ per-layer metric is found by name (see ``load_cell``):
 
 * ``bench/configs/<config>.json``: the model's sizes as run, its source
   and the reference module that computes it;
+* ``bench/arch/<config>.py`` and ``bench/reference/<reference>.py``: the
+  configuration's architecture module and plain reference (``Block``);
 * ``bench/mixes/<traffic>.json``: the traffic ``kind``, its parameters,
   and the serving deployment (slots, capacity, FIER budget, chunk);
 * ``bench/traffic/<kind>.py``: the generator of that kind;
@@ -17,14 +19,17 @@ per-layer metric is found by name (see ``load_cell``):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import importlib
+import importlib.util
 import json
 import os
 import shutil
 import sys
 import time
 from pathlib import Path
+from types import ModuleType
 
 import numpy as np
 
@@ -46,10 +51,15 @@ class Cell:
     limits: dict
     end_to_end: list[dict]
     per_layer: list[dict]
+    root: Path = ROOT
 
     @property
     def deployment(self) -> dict:
         return self.mix["deployment"]
+
+    @functools.cached_property
+    def block(self) -> "Block":
+        return block(self.config, self.root)
 
 
 def load_spec(root: Path = ROOT) -> dict:
@@ -77,6 +87,7 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
         limits=_json(root / "bench" / "cells" / f"{name}.json"),
         end_to_end=[m for m in spec["end_to_end"] if applies(m)],
         per_layer=[m for m in spec["per_layer"] if applies(m)],
+        root=root,
     )
 
 
@@ -88,36 +99,75 @@ def metric_module(name: str):
     return importlib.import_module(f"bench.metrics.{name}")
 
 
+# ---------------------------------------------------- a configuration's block
+
+@dataclasses.dataclass(frozen=True)
+class Block:
+    """Everything of a configuration that follows its architecture, found
+    by name, so that a configuration with another block comes as new files.
+
+    ``arch`` is ``bench/arch/<config name>.py``.  It provides
+      ``model_config(config)``: the program's ModelConfig at the file's
+        sizes, raising where the program's block is not the file's;
+      ``layer_view(params, layer)`` and ``head_view(params, vocab)``: one
+        layer's weights, and the embedding and LM head, under the names
+        the reference reads them by;
+      ``weight_work(config, batch)``: (FLOPs, bytes) of the weights in one
+        decode step of ``batch`` tokens (``bench/work.py`` counts the rest).
+    ``reference`` is ``bench/reference/<the file's "reference">.py``.  It
+    imports nothing of the program, and provides
+      ``BLOCK``: the file's statement of the block it computes;
+      ``logits(config, dep, layer_weights, head, tokens, first_row, *,
+        lowp)``: the float32 logits of the rows from ``first_row`` on
+        (``bench/reference/fier.py``), or the float8 control's.
+    """
+    arch: ModuleType
+    reference: ModuleType
+
+    ARCH = ("model_config", "layer_view", "head_view", "weight_work")
+    REFERENCE = ("BLOCK", "logits")
+
+
+def _module(path: Path) -> ModuleType:
+    """The module in the file ``path``, imported once a process."""
+    name = f"bench_file:{path.resolve()}"
+    if name not in sys.modules:
+        if not path.is_file():
+            raise FileNotFoundError(f"no module {path}")
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
+
+
+def block(config: dict, root: Path = ROOT) -> Block:
+    """A configuration's architecture module and reference, checked to
+    provide what ``Block`` names and to compute the block the file states."""
+    b = Block(_module(root / "bench" / "arch" / f"{config['name']}.py"),
+              _module(root / "bench" / "reference" / f"{config['reference']}.py"))
+    for mod, names in ((b.arch, Block.ARCH), (b.reference, Block.REFERENCE)):
+        missing = [n for n in names if not hasattr(mod, n)]
+        if missing:
+            raise AttributeError(f"{mod.__file__} lacks {missing}")
+    stated = {k: config.get(k) for k in b.reference.BLOCK}
+    if stated != b.reference.BLOCK:
+        raise ValueError(f"{config['name']}: the file states the block {stated}, its "
+                         f"reference {config['reference']!r} computes {b.reference.BLOCK}")
+    return b
+
+
+def model_config(config: dict, root: Path = ROOT):
+    """The program's ModelConfig for a configuration file, from its
+    architecture module."""
+    return block(config, root).arch.model_config(config)
+
+
 # --------------------------------------------------------------- the program
-
-# the configuration file's names of a block's parts -> the program's
-_NORM = {"layernorm_nonparametric": "nonparametric"}
-_ACT = {"swiglu": "silu"}
-
-
-def model_config(config: dict):
-    """The program's ModelConfig for a configuration file: the program's
-    architecture entry with the file's sizes, checked against the file's
-    statement of the block's structure."""
-    from repro.configs import get_config
-
-    base = get_config(config["program_arch"])
-    cfg = dataclasses.replace(
-        base, n_layers=config["num_hidden_layers"], d_model=config["hidden_size"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"], d_head=config["head_dim"],
-        d_ff=config["intermediate_size"], vocab=config["vocab_size"],
-        rope_theta=float(config["rope_theta"]),
-        param_dtype=config["param_dtype"], compute_dtype=config["compute_dtype"],
-    )
-    want = (_NORM[config["norm"]], _ACT[config["mlp"]], config["attention_bias"],
-            config["tie_word_embeddings"])
-    have = (cfg.norm, cfg.act, cfg.qkv_bias, cfg.tie_embeddings)
-    if want != have:
-        raise ValueError(f"{config['name']}: the program's block {have} is not "
-                         f"the configuration's {want}")
-    return cfg
-
 
 def build_engine(cell: Cell):
     from repro.serving import Engine
@@ -130,7 +180,7 @@ def build_engine(cell: Cell):
                        recent=dep["recent"], pipeline="one_pass", layout="paged"),
         block_size=dep["block_size"],
     )
-    return Engine.build(model_config(cell.config), n_slots=dep["slots"],
+    return Engine.build(cell.block.arch.model_config(cell.config), n_slots=dep["slots"],
                         capacity=dep["capacity"], policy=pol, layout="paged",
                         block_size=dep["block_size"])
 
@@ -326,7 +376,9 @@ def per_layer(cell: Cell, red, step_lengths: list[list[int]], peak: dict) -> dic
     from bench import work
 
     shapes = work.Shapes.of(cell.config, cell.deployment)
-    data = RunData(red, [work.step(shapes, L) for L in step_lengths], peak)
+    weights = cell.block.arch.weight_work
+    data = RunData(red, [work.step(shapes, L, weights(cell.config, len(L)))
+                         for L in step_lengths], peak)
     metrics = {}
     for m in cell.per_layer:
         v = metric_module(m["name"]).read(data)
@@ -366,6 +418,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *, t0: float,
     log(f"window s {served.window_s:.6f}: {len(served.step_spans)} steps, "
         f"{served.tokens} tokens; compile s inside the window "
         f"{served.compile_s:.6f} ({served.compiles} compilations)")
+    steps = np.array([e - s for s, e in served.step_spans]) * 1e3
+    slow = np.argsort(steps)[::-1][:5]
+    log(f"step ms: median {np.median(steps):.3f}; slowest (step, ms) "
+        f"{[(int(i), round(float(steps[i]), 3)) for i in slow]}")
     log(f"itl samples {len(served.gaps_s)} (p95 over them); sessions "
         f"{served.attempted}, failed {served.failed}")
     log(f"peak_bytes_in_use {served.peak_bytes}; params bytes "
@@ -390,7 +446,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *, t0: float,
     # the comparison, once the window is closed and its state freed
     t_ref = time.perf_counter()
     picked = correctness.sample(served.sessions, cell.mix["checked_sessions"], seed)
-    gaps = correctness.gaps(cell.config, cell.deployment, params, picked, lowp=control)
+    gaps = correctness.gaps(cell.block, cell.config, cell.deployment, params, picked,
+                            lowp=control)
     gap = float(max(g.max() for g in gaps))
     n_checked = int(sum(len(g) for g in gaps))
     log(f"reference s {time.perf_counter() - t_ref:.3f} over {len(picked)} "

@@ -1,16 +1,20 @@
 """Random weights from ``--seed``, made on the device in one jitted call,
-in the program's parameter layout and the type it serves them in (f32);
-and the per-layer view the plain reference reads them through.
+in the program's parameter layout and the type it serves them in.
 
-This module is the one place that knows the program's parameter layout.
-Scales follow the usual fan-in init: matrices N(0, 1/fan_in), the
-embedding N(0, 1/d_model).  The layout is OLMo's: no norm parameters, no
-biases, a tied LM head.
+The rules follow the program's layout alone: a leaf under ``layers``
+carries a leading layer axis, and the rest is one layer's shape.
+Matrices are drawn N(0, 1/fan_in), the embedding N(0, 1/d_model), and a
+vector (a norm's gain) 1 + 0.1·N(0, 1), so that a reference that leaves
+a gain out reads a different model.  Which leaf a reference reads as
+what is the configuration's architecture module's (``layer_view``,
+``head_view`` in ``bench/arch/<config>.py``).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+GAIN_NOISE = 0.1
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -23,11 +27,14 @@ def seed_key(seed: int) -> jax.Array:
 
 def _leaf(names: tuple[str, ...], sds, key) -> jax.Array:
     shape, dtype = sds.shape, sds.dtype
+    one = shape[1:] if names[0] == "layers" else shape     # one layer's shape
     noise = jax.random.normal(key, shape, jnp.float32)
     if names[-1] == "embed":
         x = noise * shape[-1] ** -0.5
-    elif len(shape) >= 2:                   # a matrix [..., fan_in, fan_out]
+    elif len(one) >= 2:                     # a matrix [..., fan_in, fan_out]
         x = noise * shape[-2] ** -0.5
+    elif len(one) == 1:
+        x = 1.0 + GAIN_NOISE * noise
     else:
         raise ValueError(f"no rule for the parameter {'/'.join(names)} {shape}")
     return x.astype(dtype)
@@ -52,19 +59,3 @@ def make(shapes, seed: int):
 
 def nbytes(tree) -> int:
     return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
-
-
-def layer_view(params, layer: int) -> dict:
-    """Layer ``layer`` under the reference's names."""
-    lp = jax.tree.map(lambda a: a[layer], params["layers"])
-    attn, mlp = lp["attn"], lp["mlp"]
-    return {"wq": attn["wq"], "wk": attn["wk"], "wv": attn["wv"], "wo": attn["wo"],
-            "w_gate": mlp["w1"], "w_up": mlp["w3"], "w_down": mlp["w2"]}
-
-
-def embed(params, vocab: int) -> jax.Array:
-    """The [vocab, d] embedding (the program pads its rows), which is also
-    the tied LM head."""
-    if "lm_head" in params:
-        raise ValueError("the program's head is not tied to its embedding")
-    return params["embed"][:vocab]

@@ -10,9 +10,11 @@ these rooflines and never pass it.
 Per decode step, with ``L`` the keys a slot attends over (its resident
 tokens, the new one included):
 
-* weights: every matmul weight (OLMo's block: SwiGLU MLP, no biases, no
-  norm parameters), plus the token's embedding row, in bf16; the tied LM
-  head is the embedding matrix;
+* weights: the FLOPs and bytes the configuration's architecture module
+  counts for a batch of B tokens (``weight_work`` in
+  ``bench/arch/<config>.py``), since those follow the block: a dense MLP,
+  or the routed experts;
+* the token's embedding row, in bf16;
 * dense attention on the first ``skip_layers`` layers: all ``L`` K and V
   rows of every KV head;
 * FIER retrieval on the other layers: 1 bit a key channel plus the bf16
@@ -22,6 +24,9 @@ tokens, the new one included):
 * FIER attention: ``min(budget, L)`` K and V rows per (slot, KV head),
   the bf16 query in and the f32 output out;
 * the new token's K and V written to every layer.
+
+All but the weights depend only on the attention's shapes, so every
+configuration's FIER kernels are counted alike.
 """
 from __future__ import annotations
 
@@ -39,8 +44,6 @@ class Shapes:
     heads: int
     kv_heads: int
     head_dim: int
-    d_ff: int
-    vocab: int
     budget: int
     group: int
     skip_layers: int
@@ -51,18 +54,9 @@ class Shapes:
             layers=config["num_hidden_layers"], d_model=config["hidden_size"],
             heads=config["num_attention_heads"],
             kv_heads=config["num_key_value_heads"], head_dim=config["head_dim"],
-            d_ff=config["intermediate_size"], vocab=config["vocab_size"],
             budget=deployment["budget"], group=deployment["group"],
             skip_layers=deployment["skip_layers"],
         )
-
-
-def matmul_params(s: Shapes) -> int:
-    """Parameters of the matmuls one token passes through (head included)."""
-    d, D = s.d_model, s.head_dim
-    attn = d * (s.heads + 2 * s.kv_heads) * D + s.heads * D * d
-    mlp = 3 * d * s.d_ff
-    return s.layers * (attn + mlp) + s.vocab * d
 
 
 def retrieve(s: Shapes, L: int) -> tuple[float, float]:
@@ -91,13 +85,13 @@ def dense(s: Shapes, L: int) -> tuple[float, float]:
     return n * 4.0 * s.heads * L * s.head_dim, n * float(rows + qo)
 
 
-def step(s: Shapes, lengths: list[int]) -> dict[str, float]:
+def step(s: Shapes, lengths: list[int], weights: tuple[float, float]) -> dict[str, float]:
     """Work of one batched decode step; ``lengths`` holds, per running
-    slot, the keys it attends over.  FLOPs and bytes of the whole step and
-    of its two FIER kernels."""
+    slot, the keys it attends over, and ``weights`` the (FLOPs, bytes) of
+    the block's weights for that batch.  FLOPs and bytes of the whole step
+    and of its two FIER kernels."""
     B = len(lengths)
-    flops = 2.0 * B * matmul_params(s)
-    nbytes = float(matmul_params(s) * BF16)
+    flops, nbytes = weights
     nbytes += B * s.d_model * BF16                       # embedding rows
     nbytes += B * s.layers * s.kv_heads * s.head_dim * 2 * BF16   # K/V append
     out = {"retrieve_flops": 0.0, "retrieve_bytes": 0.0,

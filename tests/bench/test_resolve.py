@@ -1,7 +1,6 @@
 """Every entry of BENCHMARK.json resolves by name to the files that
 define it, and the file keeps to the benchmark's contract."""
 import _bench_root  # noqa: F401  (repo root and src/ on sys.path)
-import importlib
 import json
 import re
 from pathlib import Path
@@ -52,11 +51,19 @@ def test_workload_resolves(workload):
     w = next(x for x in SPEC["workloads"] if x["name"] == workload)
     assert w["chips"] in (1, 4)
     assert set(w) == {"name", "config", "traffic", "chips", "why"}
-    # the configuration: its file, the reference it names, the program's block
+    # the configuration: its file, its architecture module, the reference
+    # it names, the program's block
     conf = next(c for c in SPEC["configs"] if c["name"] == w["config"])
     assert cell.config["name"] == conf["name"]
     assert cell.config["reduced"] == conf["reduced"]
-    importlib.import_module(f"bench.reference.{cell.config['reference']}")
+    block = cell.block
+    assert Path(block.arch.__file__) == ROOT / "bench/arch" / f"{conf['name']}.py"
+    assert Path(block.reference.__file__) == (
+        ROOT / "bench/reference" / f"{cell.config['reference']}.py")
+    for name in harness.Block.ARCH:
+        assert hasattr(block.arch, name)
+    for name in harness.Block.REFERENCE:
+        assert hasattr(block.reference, name)
     harness.model_config(cell.config)
     # the traffic: its mix file, the generator of its kind, the limits
     gen = harness.traffic_module(cell.mix["kind"])
