@@ -22,11 +22,17 @@ class ModelConfig:
     rope_theta: float = 1e4
     use_rope: bool = True
     qkv_bias: bool = False
+    qk_norm: bool = False  # per-head RMSNorm (with gains) on q and k before RoPE
+    norm_eps: float = 1e-5
     tie_embeddings: bool = False
     # MoE
     n_experts: int = 0
     topk_experts: int = 0
     capacity_factor: float = 1.25
+    # the experts this chip holds, [expert_first, expert_first + experts_held)
+    # of the n_experts the router scores; experts_held 0 holds them all
+    expert_first: int = 0
+    experts_held: int = 0
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -46,6 +52,10 @@ class ModelConfig:
     compute_dtype: str = "bfloat16"
     # notes from the source config
     source: str = ""
+
+    @property
+    def n_held(self) -> int:
+        return self.experts_held or self.n_experts
 
     @property
     def d_inner(self) -> int:  # SSM inner width

@@ -28,6 +28,7 @@ from repro.core.quantize import QuantizedKeys
 
 from . import fier_score as _fs
 from . import fused_retrieval as _fr
+from . import held_experts as _he
 from . import pack_quantize as _pq
 from . import sparse_attention as _sa
 from . import topk_select as _tk
@@ -41,6 +42,13 @@ ATTEND_BLK = 128
 
 def _interpret() -> bool:
     return jax.default_backend() == "cpu"
+
+
+def held_experts_swiglu(x: jax.Array, gates: jax.Array, w1: jax.Array, w3: jax.Array,
+                        w2: jax.Array) -> jax.Array:
+    """Grouped SwiGLU over the held experts (``held_experts``): x [T, d],
+    gates f32 [T, E_held] → f32 [T, d]."""
+    return _he.held_experts_swiglu(x, gates, w1, w3, w2, interpret=_interpret())
 
 
 def fier_score(q: jax.Array, qk: QuantizedKeys, *, blk_s: int = 512) -> jax.Array:

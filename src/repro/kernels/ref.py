@@ -208,3 +208,16 @@ def paged_fused_fier_attention_decode(
     idx = retrieval.select_topk(kv, budget, length, sink=sink, recent=recent)
     k_sel, v_sel = retrieval.gather_kv(K, V, idx)
     return retrieval.sparse_attention(q, k_sel, v_sel, idx, length)
+
+
+def held_experts_swiglu(
+    x: jax.Array, gates: jax.Array, w1: jax.Array, w3: jax.Array, w2: jax.Array
+) -> jax.Array:
+    """Oracle for ``held_experts.held_experts_swiglu``: every held expert
+    on every token, weighted by its gate (0 where the token does not route
+    to it), as f32 [T, d]."""
+    f32 = lambda a: a.astype(jnp.float32)
+    a = jnp.einsum("td,edf->tef", f32(x), f32(w1))
+    b = jnp.einsum("td,edf->tef", f32(x), f32(w3))
+    h = jax.nn.silu(a) * b * f32(gates)[..., None]
+    return jnp.einsum("tef,efd->td", h, f32(w2))

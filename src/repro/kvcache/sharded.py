@@ -237,6 +237,8 @@ def shard_cache(cache: dict, spec: ShardSpec) -> dict:
             out[name] = put(val, P(dp, None))
         elif name == "length":
             out[name] = put(val, P(dp))
+        elif name == "moe_counts":
+            out[name] = put(val, P())
         else:
             out[name] = jax.tree.map(lambda x: put(x, leaf_spec(x)), val)
     return out

@@ -24,7 +24,7 @@ from repro.kvcache import cache as kvcache
 from repro.kvcache import paged as kvcache_paged
 from repro.kvcache import sharded as kvcache_sharded
 
-from .layers import apply_rope, flash_attention, init_linear, wuse
+from .layers import apply_rope, flash_attention, init_linear, rms_norm, wuse
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,6 +87,9 @@ def init_attention(rng: jax.Array, cfg: ModelConfig, d_in: int | None = None) ->
         p["bq"] = jnp.zeros((cfg.n_heads * cfg.d_head,), jnp.float32)
         p["bk"] = jnp.zeros((cfg.n_kv_heads * cfg.d_head,), jnp.float32)
         p["bv"] = jnp.zeros((cfg.n_kv_heads * cfg.d_head,), jnp.float32)
+    if cfg.qk_norm:
+        p["q_norm"] = jnp.ones((cfg.d_head,), jnp.float32)
+        p["k_norm"] = jnp.ones((cfg.d_head,), jnp.float32)
     return p
 
 
@@ -100,11 +103,15 @@ def _proj(x: jax.Array, w: jax.Array, b: jax.Array | None) -> jax.Array:
 def qkv_proj(
     p: dict, x: jax.Array, cfg: ModelConfig, positions: jax.Array | None
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """x: [B, S, d] → q [B,S,Hq,D], k/v [B,S,Hkv,D] (RoPE applied)."""
+    """x: [B, S, d] → q [B,S,Hq,D], k/v [B,S,Hkv,D] (QK-norm, then RoPE,
+    applied: the cache holds the normed keys)."""
     B, S, _ = x.shape
     q = _proj(x, p["wq"], p.get("bq")).reshape(B, S, cfg.n_heads, cfg.d_head)
     k = _proj(x, p["wk"], p.get("bk")).reshape(B, S, cfg.n_kv_heads, cfg.d_head)
     v = _proj(x, p["wv"], p.get("bv")).reshape(B, S, cfg.n_kv_heads, cfg.d_head)
+    if cfg.qk_norm:
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     if cfg.use_rope:
         if positions is None:
             positions = jnp.arange(S, dtype=jnp.int32)[None, :]

@@ -64,14 +64,14 @@ def layer_norm(
     return y.astype(x.dtype)
 
 
-def apply_norm(x: jax.Array, p: dict, kind: str) -> jax.Array:
+def apply_norm(x: jax.Array, p: dict, kind: str, eps: float = 1e-5) -> jax.Array:
     """kind: rms | layernorm | nonparametric (OLMo: LN with no learnables)."""
     if kind == "rms":
-        return rms_norm(x, p["w"])
+        return rms_norm(x, p["w"], eps)
     if kind == "layernorm":
-        return layer_norm(x, p.get("w"), p.get("b"))
+        return layer_norm(x, p.get("w"), p.get("b"), eps)
     if kind == "nonparametric":
-        return layer_norm(x, None, None)
+        return layer_norm(x, None, None, eps)
     raise ValueError(f"unknown norm {kind!r}")
 
 

@@ -1,6 +1,13 @@
 """Top-k routed MoE FFN (Granite 32e/top-8, Qwen3-MoE 128e/top-8).
 
-Capacity-based scatter dispatch (Megablocks-style, GShard capacity):
+Serving (prefill, chunked prefill, decode) runs ``moe_held``: the drop-free
+expert layer at this chip's share.  The router scores all ``n_experts``;
+the layer computes the part of the result that the experts it holds give
+(``ModelConfig.expert_first`` / ``experts_held``), through the
+``held_experts_swiglu`` kernel, and no token's output depends on the
+other tokens of its batch.
+
+Training uses capacity-based scatter dispatch (Megablocks-style, GShard capacity):
 tokens are scattered into per-expert buckets ``[E, C, d]``, experts run as
 one batched matmul, outputs gather back weighted by the renormalised top-k
 router probs.  Overflow tokens drop (capacity_factor bounds memory — the
@@ -16,20 +23,52 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops
 
 from .layers import init_linear
 
 
 def init_moe(rng: jax.Array, cfg: ModelConfig) -> dict:
-    d, ff, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """The router over all ``n_experts``, and the held experts' weights."""
+    d, ff, E, H = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.n_held
     kr, k1, k2, k3 = jax.random.split(rng, 4)
     s_in, s_out = d**-0.5, ff**-0.5
     return {
         "router": init_linear(kr, d, E),
-        "w1": jax.random.normal(k1, (E, d, ff), jnp.float32) * s_in,
-        "w3": jax.random.normal(k3, (E, d, ff), jnp.float32) * s_in,
-        "w2": jax.random.normal(k2, (E, ff, d), jnp.float32) * s_out,
+        "w1": jax.random.normal(k1, (H, d, ff), jnp.float32) * s_in,
+        "w3": jax.random.normal(k3, (H, d, ff), jnp.float32) * s_in,
+        "w2": jax.random.normal(k2, (H, ff, d), jnp.float32) * s_out,
     }
+
+
+def route(x: jax.Array, router: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.Array]:
+    """Router in f32 over all ``n_experts``: softmax, top-k, the top-k
+    weights renormalised (``norm_topk_prob``).  x [T, d] → (gates f32
+    [T, E_held] of the held experts, 0 where a token does not route to
+    one; routes int32 [T, E_held], 1 where it does)."""
+    first, H = cfg.expert_first, cfg.n_held
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    top, eidx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.topk_experts)
+    top = top / top.sum(axis=-1, keepdims=True)
+    rel = eidx - first
+    # a route to an expert held elsewhere one-hots to nothing
+    hit = jax.nn.one_hot(jnp.where((rel >= 0) & (rel < H), rel, H), H,
+                         dtype=jnp.float32)                      # [T, k, H]
+    return (hit * top[..., None]).sum(axis=1), hit.sum(axis=1).astype(jnp.int32)
+
+
+def moe_held(x: jax.Array, p: dict, cfg: ModelConfig) -> tuple[jax.Array, jax.Array]:
+    """The drop-free expert layer at this chip's share: x [T, d] → (y [T, d],
+    Σ over the token's top-k experts held here of gate · SwiGLU_e(x);
+    counts int32 [2]: routes that landed on held experts, held experts
+    touched).  Experts held elsewhere add nothing."""
+    gates, routes = route(x, p["router"], cfg)
+    w = [p[n].astype(x.dtype) for n in ("w1", "w3", "w2")]
+    y = ops.held_experts_swiglu(x, gates, *w)
+    per_expert = routes.sum(axis=0)
+    counts = jnp.stack([per_expert.sum(), (per_expert > 0).sum()]).astype(jnp.int32)
+    return y.astype(x.dtype), counts
 
 
 def moe_apply(
@@ -171,28 +210,3 @@ def moe_apply_ep(
         check_vma=False,
     )
     return f(x, p["router"], p["w1"], p["w3"], p["w2"])
-
-
-def moe_apply_masked(
-    x: jax.Array, p: dict, cfg: ModelConfig
-) -> tuple[jax.Array, jax.Array]:
-    """Dense-masked MoE for DECODE (token count ≈ batch size): computes all
-    experts for all tokens as plain einsums — at decode scale this costs
-    E/k× waste on a negligible FLOP total, in exchange for perfectly
-    GSPMD-shardable ops (E over 'model', no scatter).  Not for training."""
-    T, d = x.shape
-    E, k = cfg.n_experts, cfg.topk_experts
-    logits = x.astype(jnp.float32) @ p["router"]
-    gvals, eidx = jax.lax.top_k(logits, k)
-    gates = jax.nn.softmax(gvals, axis=-1)
-    g_full = jnp.sum(jax.nn.one_hot(eidx, E) * gates[..., None], axis=1)  # [T,E]
-    h1 = jax.nn.silu(
-        jnp.einsum("td,edf->tef", x, p["w1"].astype(x.dtype))
-    ) * jnp.einsum("td,edf->tef", x, p["w3"].astype(x.dtype))
-    y = jnp.einsum(
-        "tef,efd,te->td", h1, p["w2"].astype(x.dtype), g_full.astype(x.dtype)
-    )
-    probs = jax.nn.softmax(logits, axis=-1)
-    f = jnp.mean(jax.nn.one_hot(eidx.reshape(-1), E).reshape(T, k, E).sum(1), axis=0)
-    aux = E * jnp.sum(f / k * probs.mean(0))
-    return y.astype(x.dtype), aux

@@ -126,13 +126,20 @@ def build(
             return params["embed"].T
         return params["lm_head"]
 
+    def norm(x, p):
+        return apply_norm(x, p, cfg.norm, cfg.norm_eps)
+
     def _ffn(lp, x2, B, S, mode="train"):
+        """(y, aux).  The served paths (``mode="serve"``) run an MoE layer
+        as the drop-free layer over the experts held here, and ``aux`` is
+        then its route counts (``moe.moe_held``)."""
         if not is_moe:
             return mlp_apply(x2, lp["mlp"], cfg.act), jnp.float32(0.0)
         x2d = x2.reshape(B * S, cfg.d_model)
-        if mode == "decode":
-            # T ≈ batch: dense-masked einsum path (GSPMD-friendly, no scatter)
-            y, aux = moe_mod.moe_apply_masked(x2d, lp["moe"], cfg)
+        if mode == "serve":
+            y, aux = moe_mod.moe_held(x2d, lp["moe"], cfg)
+        elif cfg.n_held != cfg.n_experts:
+            raise ValueError("training needs every expert held on the chip")
         elif dcfg is not None and dcfg.ep_axis is not None:
             # pod scale: shard_map expert parallelism
             tok = tuple(dcfg.batch_axes)
@@ -147,9 +154,9 @@ def build(
     # --------------------------------------------------------------- train
     def _layer_train(h, lp):
         B, S, _ = h.shape
-        a = attn.attention_train(lp["attn"], apply_norm(h, lp["norm1"], cfg.norm), cfg)
+        a = attn.attention_train(lp["attn"], norm(h, lp["norm1"]), cfg)
         h = h + a
-        y, aux = _ffn(lp, apply_norm(h, lp["norm2"], cfg.norm), B, S)
+        y, aux = _ffn(lp, norm(h, lp["norm2"]), B, S)
         # sequence-parallel residual stream: bounds the remat-saved
         # activation at L·B·S·d/(data·model) per device
         return attn.seq_shard_constraint(h + y, dcfg), aux
@@ -163,7 +170,7 @@ def build(
     def train_loss(params, batch):
         h = _embed_inputs(params, batch)
         h, auxs = maybe_scan(layer_train, h, params["layers"])
-        h = apply_norm(h, params["final_norm"], cfg.norm)
+        h = norm(h, params["final_norm"])
         loss, n_tok = _chunked_ce(
             h, _head(params), batch["targets"], batch["loss_mask"], cfg.vocab, Vp,
             loss_chunk,
@@ -183,12 +190,12 @@ def build(
         valid = kvcache.valid_mask(S, lengths)
 
         def layer_fn(hc, lp):
-            xn = apply_norm(hc, lp["norm1"], cfg.norm)
+            xn = norm(hc, lp["norm1"])
             q, k, v = attn.qkv_proj(lp["attn"], xn, cfg, positions=None)
             o = attn.flash_attention(q, k, v, causal=True, bias_mask=valid)
             o = o.reshape(B, S, cfg.n_heads * cfg.d_head) @ lp["attn"]["wo"].astype(hc.dtype)
             hc = hc + o
-            y, _ = _ffn(lp, apply_norm(hc, lp["norm2"], cfg.norm), B, S)
+            y, _ = _ffn(lp, norm(hc, lp["norm2"]), B, S, "serve")
             pad = ((0, 0), (0, cap - S), (0, 0), (0, 0))
             return attn.seq_shard_constraint(hc + y, dcfg), (
                 jnp.pad(k.astype(jnp.bfloat16), pad),
@@ -196,7 +203,7 @@ def build(
             )
 
         h, (K, V) = maybe_scan(layer_fn, h, params["layers"])  # K: [L,B,cap,H,D]
-        h = apply_norm(h, params["final_norm"], cfg.norm)
+        h = norm(h, params["final_norm"])
         cache = _assemble_cache(K, V, lengths)
         last = jnp.take_along_axis(h, (lengths - 1)[:, None, None], axis=1)[:, 0]
         logits = _masked_logits(last, _head(params), cfg.vocab, Vp)
@@ -209,7 +216,12 @@ def build(
             from repro.core.policy import build_metadata
 
             rest["meta"] = jax.vmap(lambda Kl: build_metadata(Kl, pol))(rest["k"])
-        return {"front": front, "rest": rest, "length": lengths}
+        return {"front": front, "rest": rest, "length": lengths, **_moe_counts()}
+
+    def _moe_counts():
+        # an MoE decode step's route counts, summed over its layers
+        # (routes on held experts, held experts touched)
+        return {"moe_counts": jnp.zeros((2,), jnp.int32)} if is_moe else {}
 
     def init_cache(B, capacity, length):
         # capacity-dependent plan validation happens here, where capacity
@@ -237,6 +249,7 @@ def build(
                 ),
                 "length": jnp.full((B,), length, jnp.int32),
                 "block_table": jnp.zeros((B, n_btab), jnp.int32),
+                **_moe_counts(),
             }
         c = {
             "front": kvcache.init_layer_cache(
@@ -247,6 +260,7 @@ def build(
                 pol if pol.kind != "full" else None,
             ),
             "length": jnp.full((B,), length, jnp.int32),
+            **_moe_counts(),
         }
         return c
 
@@ -293,7 +307,7 @@ def build(
 
         def chunk_body(hc, xs):
             lp, lc = xs
-            xn = apply_norm(hc, lp["norm1"], cfg.norm)
+            xn = norm(hc, lp["norm1"])
             q, k, v = attn.qkv_proj(lp["attn"], xn, cfg, positions=positions)
             kc, vc = k.astype(lc["k"].dtype), v.astype(lc["v"].dtype)
             if paged:
@@ -324,7 +338,7 @@ def build(
                 o = jax.lax.with_sharding_constraint(o, replicated)
             o = o.reshape(1, n, cfg.n_heads * cfg.d_head) @ lp["attn"]["wo"].astype(hc.dtype)
             hc = hc + o
-            y, _ = _ffn(lp, apply_norm(hc, lp["norm2"], cfg.norm), 1, n)
+            y, _ = _ffn(lp, norm(hc, lp["norm2"]), 1, n, "serve")
             new_lc = dict(lc, k=lck, v=lcv)
             if final:
                 row_valid = (jnp.arange(cap, dtype=jnp.int32) < total)
@@ -378,7 +392,7 @@ def build(
         new_cache["length"] = cache["length"].at[slot].set(total)
         if "block_table" in cache:
             new_cache["block_table"] = cache["block_table"].at[slot].set(table_row)
-        h = apply_norm(h, params["final_norm"], cfg.norm)[:, n - 1]
+        h = norm(h, params["final_norm"])[:, n - 1]
         return _masked_logits(h, _head(params), cfg.vocab, Vp), new_cache
 
     # -------------------------------------------------------------- decode
@@ -394,34 +408,40 @@ def build(
         B = x.shape[0]
 
         def mk_body(layer_plan, use_dist):
-            def body(h, xs):
+            # an MoE step carries its route counts through the layers
+            def body(carry, xs):
                 lp, lc = xs
+                h, counts = carry if is_moe else (carry, None)
                 o, lc = attn.decode_self_attention(
-                    lp["attn"], apply_norm(h, lp["norm1"], cfg.norm), lc, length,
+                    lp["attn"], norm(h, lp["norm1"]), lc, length,
                     cfg, layer_plan, dcfg if use_dist else None,
                     block_table=block_table,
                 )
                 h = h + o
-                y, _ = _ffn(lp, apply_norm(h, lp["norm2"], cfg.norm), B, 1, "decode")
-                return h + y, lc
+                y, aux = _ffn(lp, norm(h, lp["norm2"]), B, 1, "serve")
+                return ((h + y, counts + aux) if is_moe else h + y), lc
 
             return body
 
-        h, front_cache = _layer_loop(
-            mk_body(plan_full, use_dist=False), x, params["layers"],
+        carry = (x, jnp.zeros((2,), jnp.int32)) if is_moe else x
+        carry, front_cache = _layer_loop(
+            mk_body(plan_full, use_dist=False), carry, params["layers"],
             cache["front"], 0,
         )
-        h, rest_cache = _layer_loop(
-            mk_body(plan, use_dist=True), h, params["layers"], cache["rest"],
+        carry, rest_cache = _layer_loop(
+            mk_body(plan, use_dist=True), carry, params["layers"], cache["rest"],
             skip,
         )
-        h = apply_norm(h, params["final_norm"], cfg.norm)[:, 0]
+        h, counts = carry if is_moe else (carry, None)
+        h = norm(h, params["final_norm"])[:, 0]
         logits = _masked_logits(h, _head(params), cfg.vocab, Vp)
         new_cache = {
             "front": front_cache,
             "rest": rest_cache,
             "length": length + 1,
         }
+        if is_moe:
+            new_cache["moe_counts"] = counts
         if block_table is not None:
             new_cache["block_table"] = block_table
         return logits, new_cache

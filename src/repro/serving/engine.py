@@ -118,18 +118,25 @@ def sample_token(rng, logits: jax.Array, cfg: SamplingConfig) -> jax.Array:
     return jax.random.categorical(rng, l, axis=-1).astype(jnp.int32)
 
 
+# cache leaves that belong to the step, not to a slot: no batch axis
+_STEP_LEAVES = ("moe_counts",)   # an MoE decode step's route counts
+
+
 def _cache_batch_axes(bundle: ModelBundle, capacity: int) -> Any:
-    """Pytree of batch-axis indices, discovered by shape-diffing."""
+    """Pytree of batch-axis indices, discovered by shape-diffing; -1 for a
+    leaf of ``_STEP_LEAVES``, which no slot owns."""
     c2 = jax.eval_shape(lambda: bundle.init_cache(2, capacity, 0))
     c3 = jax.eval_shape(lambda: bundle.init_cache(3, capacity, 0))
 
-    def axis(a, b):
+    def axis(path, a, b):
+        if getattr(path[0], "key", None) in _STEP_LEAVES:
+            return -1
         diffs = [i for i, (x, y) in enumerate(zip(a.shape, b.shape)) if x != y]
         if len(diffs) != 1:
             raise ValueError(f"ambiguous batch axis: {a.shape} vs {b.shape}")
         return diffs[0]
 
-    return jax.tree.map(axis, c2, c3)
+    return jax.tree.map_with_path(axis, c2, c3)
 
 
 class Engine:
@@ -268,6 +275,9 @@ class Engine:
             self.tokens_recomputed = 0
             self._recall_units = 0.0
             self._seq: dict[int, SeqBlocks] = {}
+            # fresh tail blocks advance_slot allocated for the next decode
+            # step, which scrubs them and enters them in the table
+            self._opened: dict[int, int] = {}
             self._prompt_logits: OrderedDict[int, np.ndarray] = OrderedDict()
             self._paged_scatter = jax.jit(
                 self._paged_scatter_impl, donate_argnums=(0,)
@@ -283,7 +293,6 @@ class Engine:
                 self._set_table_entry_impl, donate_argnums=(0,)
             )
             self._copy_block = jax.jit(self._copy_block_impl, donate_argnums=(0,))
-            self._zero_block = jax.jit(self._zero_block_impl, donate_argnums=(0,))
         else:
             self.offload = None
             self._batch_axes = _cache_batch_axes(bundle, capacity)
@@ -294,18 +303,58 @@ class Engine:
     def _make_decode_fns(self, bundle: ModelBundle):
         """Jitted (decode, decode_active) pair for one bundle — rebuilt
         per budget rung by the degradation ladder (the cache pytree is
-        budget-independent, so swapping fns never invalidates a cache)."""
-        dec = jax.jit(bundle.decode_step, donate_argnums=self._donate)
+        budget-independent, so swapping fns never invalidates a cache).
+        ``opened``: the paged engine's fresh tail blocks (``_open_blocks``)."""
 
-        def _decode_active_impl(params, tokens, cache, active):
+        def _decode_impl(params, tokens, cache, opened=None):
+            return bundle.decode_step(params, tokens, self._open_blocks(cache, opened))
+
+        def _decode_active_impl(params, tokens, cache, active, opened=None):
             old_len = cache["length"]
-            logits, new_cache = bundle.decode_step(params, tokens, cache)
+            logits, new_cache = bundle.decode_step(
+                params, tokens, self._open_blocks(cache, opened))
             new_cache = dict(
                 new_cache, length=jnp.where(active, new_cache["length"], old_len)
             )
             return logits, new_cache
 
-        return dec, jax.jit(_decode_active_impl, donate_argnums=self._donate)
+        return (jax.jit(_decode_impl, donate_argnums=self._donate),
+                jax.jit(_decode_active_impl, donate_argnums=self._donate))
+
+    def _open_blocks(self, cache, opened):
+        """Open the tail blocks ``advance_slot`` allocated for this decode
+        step, inside the step's own program, so that a step in which a
+        slot crosses a block boundary dispatches nothing more than any
+        other.  ``opened`` [n_slots] int32 holds each slot's fresh block,
+        -1 for none.  Each fresh block is scrubbed, since a recycled block
+        carries stale K/V and group stats and the token-append metadata
+        update *merges* with the stats already in the block (outputs would
+        otherwise depend on pool recycling history), and is entered in its
+        slot's table row at the write position."""
+        if opened is None:
+            return cache
+        table = cache["block_table"]
+        slots = jnp.arange(table.shape[0])
+        j = jnp.minimum(cache["length"] // self.block_size, table.shape[1] - 1)
+        fresh = opened >= 0
+        table = table.at[slots, j].set(jnp.where(fresh, opened, table[slots, j]))
+
+        def scrub(pool):
+            # one in-place update a slot (a scatter over all slots at once
+            # makes XLA copy the pool to another layout and back); a slot
+            # with no fresh block rewrites the null block with its own rows
+            for s in range(opened.shape[0]):
+                b = jnp.where(fresh[s], opened[s], NULL_BLOCK)
+                rows = jax.lax.dynamic_slice_in_dim(pool, b, 1, axis=1)
+                pool = jax.lax.dynamic_update_slice_in_dim(
+                    pool, jnp.where(fresh[s], jnp.zeros_like(rows), rows), b, axis=1)
+            return pool
+
+        return dict(
+            cache, block_table=table,
+            front=jax.tree.map(scrub, cache["front"]),
+            rest=jax.tree.map(scrub, cache["rest"]),
+        )
 
     # ------------------------------------------------------- shard routing
     def _make_allocator(self):
@@ -487,6 +536,7 @@ class Engine:
                 self.set_pool_clock(self._pool_clock)
             self._recall_units = 0.0
             self._seq = {}
+            self._opened = {}
             self._prompt_logits = OrderedDict()
         cache = self.bundle.init_cache(self.n_slots, self.capacity, length)
         if self.shard is not None:
@@ -519,6 +569,8 @@ class Engine:
 
     def _insert_impl(self, batched_cache, single_cache, slot):
         def put(dest, src, ax):
+            if ax < 0:
+                return dest
             return jax.lax.dynamic_update_index_in_dim(dest, src[0], slot, ax)
 
         return jax.tree.map(put, batched_cache, single_cache, self._batch_axes)
@@ -600,23 +652,6 @@ class Engine:
             cache,
             front=jax.tree.map(cp, cache["front"]),
             rest=jax.tree.map(cp, cache["rest"]),
-        )
-
-    def _zero_block_impl(self, cache, bid):
-        """Scrub a recycled block before a decode-time append lands in it.
-        The token-append metadata update *merges* with the group stats
-        already in the block, so a recycled block's stale stats would leak
-        into the new tokens' quantization scales — outputs would depend on
-        pool recycling history.  Zeroing restores the never-used-block
-        contents, making decode bit-identical regardless of pool pressure."""
-
-        def z(pool):
-            return pool.at[:, bid].set(jnp.zeros_like(pool[:, bid]))
-
-        return dict(
-            cache,
-            front=jax.tree.map(z, cache["front"]),
-            rest=jax.tree.map(z, cache["rest"]),
         )
 
     def _read_block_impl(self, cache, bid):
@@ -1023,7 +1058,8 @@ class Engine:
         allocated block: allocate a fresh tail block on a block boundary,
         or copy-on-write a shared tail.  Returns (ok, cache); ok=False
         means the pool is dry — the caller preempts someone and retries.
-        Must be called once per running slot before every decode step.
+        Must be called once per running slot before every decode step; a
+        fresh tail block reaches the device with that step (``decode``).
         """
         seq = self._seq[slot]
         pos = seq.length
@@ -1036,15 +1072,12 @@ class Engine:
             bid = self._alloc_block(slot)
             if bid is None:
                 return False, cache
-            # recycled blocks carry stale K/V and group stats; the append-
-            # time metadata update merges with what's resident, so scrub
-            # (demoting any evicted prefix block first — zeroing destroys it)
+            # the decode step scrubs the block and enters it in the table
+            # (_open_blocks); demote any evicted prefix block first, while
+            # the pool still holds it
             cache = self._drain_evictions(cache)
-            cache = self._zero_block(cache, jnp.int32(bid))
             seq.blocks.append(bid)
-            cache = self._set_table_entry(
-                cache, jnp.int32(slot), jnp.int32(j), jnp.int32(bid)
-            )
+            self._opened[slot] = bid
         else:
             b = seq.blocks[j]
             if self.allocator.ref[b] > 1:
@@ -1067,6 +1100,7 @@ class Engine:
         registered blocks park in the prefix cache) and zero the table
         row, so the slot's scratch decode writes hit the null block."""
         seq = self._seq.pop(slot, None)
+        self._opened.pop(slot, None)
         if seq is not None:
             for b in seq.blocks:
                 if b != NULL_BLOCK:  # shed middle blocks leave null holes
@@ -1330,7 +1364,6 @@ class Engine:
                 set_slot_state=self._set_slot_state,
                 set_table_entry=self._set_table_entry,
                 copy_block=self._copy_block,
-                zero_block=self._zero_block,
                 read_block=self._read_block,
                 write_block=self._write_block,
             )
@@ -1368,12 +1401,20 @@ class Engine:
         behaviour re-used ``PRNGKey(0)`` each step, so temperature > 0
         serving resampled the same draw forever).
         """
+        opened = None
+        if self.paged:
+            fresh = np.full((self.n_slots,), -1, np.int32)
+            for slot, bid in self._opened.items():
+                fresh[slot] = bid
+            self._opened.clear()
+            opened = jnp.asarray(fresh)
         if active is not None:
             # inactive slots' lengths are frozen inside the jitted step
             # (their cache writes are scratch, overwritten on insert)
-            logits, new_cache = self._decode_active(params, tokens, cache, active)
+            logits, new_cache = self._decode_active(params, tokens, cache, active,
+                                                    opened)
         else:
-            logits, new_cache = self._decode(params, tokens, cache)
+            logits, new_cache = self._decode(params, tokens, cache, opened)
         if rng is None:
             self._rng, rng = jax.random.split(self._rng)
         nxt = sample_token(rng, logits, self.sampling)

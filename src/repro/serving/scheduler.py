@@ -740,7 +740,13 @@ class ContinuousScheduler:
                     active=jnp.asarray(active_np), rng=step_rng,
                 )
             with span("serve.token_sync"):   # the host waits on the device
-                nxt = np.asarray(nxt)
+                if self.obs.enabled and "moe_counts" in self._cache:
+                    # the expert layer's route counts ride on the tokens'
+                    # own readback
+                    nxt, routed = jax.device_get((nxt, self._cache["moe_counts"]))
+                    self._count_routes(routed)
+                else:
+                    nxt = np.asarray(nxt)
             self.steps += 1
             self.occupancy.append(len(self.running))
             self.vtime += len(self.running)
@@ -774,6 +780,16 @@ class ContinuousScheduler:
                 self._flush_step_obs()
         self.health.maybe_audit(self.engine, self.steps)
         return StepReport(progressed, self._step_retired)
+
+    def _count_routes(self, routed) -> None:
+        """An MoE decode step's route counts (summed over its layers) into
+        the registry."""
+        m = self.obs.metrics
+        m.counter("moe_held_routes_total",
+                  "decode routes that landed on experts held here").inc(int(routed[0]))
+        m.counter("moe_held_experts_touched_total",
+                  "held experts a decode step touched, summed over layers and steps"
+                  ).inc(int(routed[1]))
 
     def _watchdog(self, logits) -> None:
         """Read the step's logits back and quarantine ONLY the poisoned

@@ -268,7 +268,7 @@ def test_decode_program_keeps_the_name_the_trace_readers_match(setup):
     eng = Engine(mk(pool_blocks=24), n_slots=2, capacity=64)
     lowered = eng._decode_active.lower(
         params, jnp.zeros((2,), jnp.int32), eng.new_cache(),
-        jnp.ones((2,), bool))
+        jnp.ones((2,), bool), jnp.full((2,), -1, jnp.int32))
     name = str(lowered.compiler_ir().operation.attributes["sym_name"])
     assert "decode_active_impl" in name, name
 

@@ -357,6 +357,59 @@ def test_paged_engine_decode_logits_match_slab(setup):
         np.testing.assert_array_equal(a, b)
 
 
+def test_a_boundary_crossing_dispatches_nothing_before_the_decode_step(setup):
+    """advance_slot on a block boundary only books the fresh tail block:
+    the decode step itself scrubs it and enters it in the slot's table
+    row, so a crossing step dispatches no program of its own."""
+    cfg, mk, slab, params = setup
+    eng = Engine(mk(True), n_slots=2, capacity=64)
+    cache = eng.new_cache()
+    toks = jnp.asarray(np.arange(1, 17, dtype=np.int32)[None])    # two whole blocks
+    logits, cache = eng.insert(params, cache, toks, 16, slot=1)
+    ok, after = eng.advance_slot(cache, 1)
+    assert ok and after is cache
+    bid = eng._seq[1].blocks[2]
+    assert eng._opened == {1: bid} and int(cache["block_table"][1, 2]) != bid
+    tok = jnp.asarray([0, int(jnp.argmax(logits[0]))], jnp.int32)
+    _, _, cache = eng.decode(params, tok, cache, active=jnp.asarray([False, True]))
+    assert eng._opened == {} and int(cache["block_table"][1, 2]) == bid
+    # a slot released before its step books nothing for it
+    ok, cache = eng.advance_slot(cache, 1)
+    cache = eng.release_slot(cache, 1)
+    assert eng._opened == {}
+
+
+def test_a_recycled_tail_block_decodes_like_a_fresh_one(setup):
+    """A fresh tail block that comes off the free list holding another
+    request's K/V and group stats decodes exactly like a never-used one."""
+    cfg, mk, slab, params = setup
+    toks = jnp.asarray(np.arange(1, 17, dtype=np.int32)[None])
+    active = jnp.asarray([False, True])
+
+    def run(poison):
+        eng = Engine(mk(True), n_slots=2, capacity=64)
+        cache = eng.new_cache()
+        logits, cache = eng.insert(params, cache, toks, 16, slot=1)
+        if poison:
+            free = list(eng.allocator._free)
+            junk = jax.tree.map(lambda a: jnp.full_like(a, 7),
+                                eng._read_block(cache, jnp.int32(free[0])))
+            for b in free:
+                cache = eng._write_block(cache, junk, jnp.int32(b))
+        tok = jnp.asarray([0, int(jnp.argmax(logits[0]))], jnp.int32)
+        out = []
+        for _ in range(10):           # opens fresh blocks at 16 and 24
+            ok, cache = eng.advance_slot(cache, 1)
+            assert ok
+            nxt, lg, cache = eng.decode(params, tok, cache, active=active)
+            out.append(np.asarray(lg[1]))
+            tok = jnp.asarray([0, int(nxt[1])], jnp.int32)
+        return out
+
+    for a, b in zip(run(False), run(True)):
+        np.testing.assert_array_equal(a, b)
+
+
 def test_prefix_hit_skips_prefill_flops_identical_logits(setup):
     """A full-prompt prefix hit replays the cached first-token logits and
     runs zero prefill FLOPs (the cold prefill costs > 0 by flopcount)."""

@@ -283,3 +283,24 @@ def test_paged_admission_skips_blocked_head(chunk_setup):
     while sched.busy:
         sched.step()
     assert len(big.out) == 4 and len(small.out) == 4 and len(hold.out) == 20
+
+
+def test_only_the_step_s_own_cache_leaves_lack_a_batch_axis():
+    """An MoE step's route counts belong to no slot; any other cache leaf
+    without a batch axis is a mistake the engine refuses."""
+    from types import SimpleNamespace
+
+    from repro.serving.engine import _cache_batch_axes
+
+    def init_cache(B, capacity, length):
+        return {"k": jnp.zeros((2, B, capacity)), "length": jnp.zeros((B,), jnp.int32),
+                "moe_counts": jnp.zeros((2,), jnp.int32)}
+
+    axes = _cache_batch_axes(SimpleNamespace(init_cache=init_cache), 16)
+    assert axes == {"k": 1, "length": 0, "moe_counts": -1}
+
+    def unbatched(B, capacity, length):
+        return dict(init_cache(B, capacity, length), scale=jnp.zeros((4,)))
+
+    with pytest.raises(ValueError, match="ambiguous batch axis"):
+        _cache_batch_axes(SimpleNamespace(init_cache=unbatched), 16)

@@ -5,7 +5,9 @@ which never shows what Mosaic refuses: slices off the (8, 128) tiling,
 SMEM block shapes, VMEM over-use.  Here the two paged FIER kernels, the
 whole olmo-1b decode step and the DP=2 x TP=2 chunked prefill are
 compiled for a *described* v5e (``jax.experimental.topologies``) at
-olmo-1b's published widths.
+olmo-1b's published widths; and at Qwen3-MoE's share (GQA 16:1, QK-norm,
+8 of 128 experts held) both FIER kernels, the ``held_experts_swiglu``
+kernel and the whole decode step, at 8 slots x 24576 tokens, budget 2048.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and under several pytest workers a
@@ -14,6 +16,7 @@ different test sets.  Where it cannot be described, the tests skip.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
 import re
@@ -24,6 +27,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.kernels import fused_retrieval as fr
+from repro.kernels import held_experts as he
 from repro.kernels import ops
 from repro.kernels import sparse_attention as sa
 
@@ -37,6 +41,11 @@ SLOTS, BLOCK, GROUP = 4, 32, 32
 POINTS = [(8192, 1024), (32768, 4096), (12288, 1024)]
 # one v5e chip's HBM
 HBM_BYTES = 16e9
+# Qwen3-235B-A22B at one chip's share (bench/configs/qwen3-moe-235b-a22b.json):
+# the first 8 layers, experts 0-7 of 128 held, 8 slots x 24576 tokens,
+# budget 2048
+QWEN = dataclasses.replace(get_config("qwen3-moe-235b-a22b"), n_layers=8, experts_held=8)
+QWEN_SLOTS, QWEN_CAPACITY, QWEN_BUDGET = 8, 24576, 2048
 
 
 @pytest.fixture(scope="module")
@@ -73,21 +82,20 @@ def _kernel_ops(compiled) -> set[str]:
     names the device trace gives the kernels' ops, which the benchmark's
     roofline readers match (``%paged_fused_retrieve_hm.10``)."""
     return {m.rsplit(".", 1)[0] for m in re.findall(
-        r'^\s*%([\w.-]+) = .*custom_call_target="tpu_custom_call"',
+        r'^\s*(?:ROOT )?%([\w.-]+) = .*custom_call_target="tpu_custom_call"',
         compiled.as_text(), re.M)}
 
 
-@pytest.mark.parametrize("capacity,budget", POINTS)
-def test_paged_retrieve_compiles(chip, capacity, budget):
+def _retrieve_ops(chip, slots, hkv, rep, capacity, budget):
     nb = capacity // BLOCK
-    n_blocks = SLOTS * nb + 1
+    n_blocks = slots * nb + 1
     args = (
-        _sds(chip, (SLOTS, HKV, REP, D), jnp.float32),
-        _sds(chip, (n_blocks, BLOCK // 8, HKV, D), jnp.uint8),
-        _sds(chip, (n_blocks, BLOCK // GROUP, HKV, D), jnp.bfloat16),
-        _sds(chip, (n_blocks, BLOCK // GROUP, HKV, D), jnp.bfloat16),
-        _sds(chip, (SLOTS, nb), jnp.int32),
-        _sds(chip, (SLOTS,), jnp.int32),
+        _sds(chip, (slots, hkv, rep, D), jnp.float32),
+        _sds(chip, (n_blocks, BLOCK // 8, hkv, D), jnp.uint8),
+        _sds(chip, (n_blocks, BLOCK // GROUP, hkv, D), jnp.bfloat16),
+        _sds(chip, (n_blocks, BLOCK // GROUP, hkv, D), jnp.bfloat16),
+        _sds(chip, (slots, nb), jnp.int32),
+        _sds(chip, (slots,), jnp.int32),
     )
 
     def f(q, codes, scale, zero, table, lengths):
@@ -98,20 +106,19 @@ def test_paged_retrieve_compiles(chip, capacity, budget):
 
     compiled = jax.jit(f).lower(*args).compile()
     assert _custom_calls(compiled) == 1
-    assert _kernel_ops(compiled) == {"paged_fused_retrieve_hm"}
+    return _kernel_ops(compiled)
 
 
-@pytest.mark.parametrize("capacity,budget", POINTS)
-def test_paged_attend_compiles(chip, capacity, budget):
+def _attend_ops(chip, slots, hkv, rep, capacity, budget):
     nb = capacity // BLOCK
-    n_blocks = SLOTS * nb + 1
+    n_blocks = slots * nb + 1
     args = (
-        _sds(chip, (SLOTS, HKV, REP, D), jnp.bfloat16),
-        _sds(chip, (n_blocks, BLOCK, HKV, D), jnp.bfloat16),
-        _sds(chip, (n_blocks, BLOCK, HKV, D), jnp.bfloat16),
-        _sds(chip, (SLOTS, nb), jnp.int32),
-        _sds(chip, (SLOTS, HKV, budget), jnp.int32),
-        _sds(chip, (SLOTS, HKV, 1, budget), jnp.int8),
+        _sds(chip, (slots, hkv, rep, D), jnp.bfloat16),
+        _sds(chip, (n_blocks, BLOCK, hkv, D), jnp.bfloat16),
+        _sds(chip, (n_blocks, BLOCK, hkv, D), jnp.bfloat16),
+        _sds(chip, (slots, nb), jnp.int32),
+        _sds(chip, (slots, hkv, budget), jnp.int32),
+        _sds(chip, (slots, hkv, 1, budget), jnp.int8),
     )
 
     def f(q, k_pool, v_pool, table, idx, mask):
@@ -122,7 +129,46 @@ def test_paged_attend_compiles(chip, capacity, budget):
 
     compiled = jax.jit(f).lower(*args).compile()
     assert _custom_calls(compiled) == 1
-    assert _kernel_ops(compiled) == {"paged_fused_sparse_attention_hm"}
+    return _kernel_ops(compiled)
+
+
+@pytest.mark.parametrize("capacity,budget", POINTS)
+def test_paged_retrieve_compiles(chip, capacity, budget):
+    assert _retrieve_ops(chip, SLOTS, HKV, REP, capacity, budget) == {
+        "paged_fused_retrieve_hm"}
+
+
+@pytest.mark.parametrize("capacity,budget", POINTS)
+def test_paged_attend_compiles(chip, capacity, budget):
+    assert _attend_ops(chip, SLOTS, HKV, REP, capacity, budget) == {
+        "paged_fused_sparse_attention_hm"}
+
+
+def test_paged_retrieve_compiles_gqa_16(chip):
+    assert _retrieve_ops(chip, QWEN_SLOTS, QWEN.n_kv_heads, QWEN.n_heads // QWEN.n_kv_heads,
+                         QWEN_CAPACITY, QWEN_BUDGET) == {"paged_fused_retrieve_hm"}
+
+
+def test_paged_attend_compiles_gqa_16(chip):
+    assert _attend_ops(chip, QWEN_SLOTS, QWEN.n_kv_heads, QWEN.n_heads // QWEN.n_kv_heads,
+                       QWEN_CAPACITY, QWEN_BUDGET) == {"paged_fused_sparse_attention_hm"}
+
+
+@pytest.mark.parametrize("tokens", [QWEN_SLOTS, 2048])
+def test_held_experts_compiles(chip, tokens):
+    """A decode step's tokens, and a prefill chunk's, through the held
+    experts at Qwen3-MoE's widths."""
+    d, ff, held = QWEN.d_model, QWEN.d_ff, QWEN.n_held
+    args = (_sds(chip, (tokens, d), jnp.bfloat16), _sds(chip, (tokens, held), jnp.float32),
+            _sds(chip, (held, d, ff), jnp.bfloat16), _sds(chip, (held, d, ff), jnp.bfloat16),
+            _sds(chip, (held, ff, d), jnp.bfloat16))
+
+    def f(x, g, w1, w3, w2):
+        return he.held_experts_swiglu(x, g, w1, w3, w2, interpret=False)
+
+    compiled = jax.jit(f).lower(*args).compile()
+    assert _custom_calls(compiled) == 1
+    assert _kernel_ops(compiled) == {"held_experts_swiglu"}
 
 
 def test_decode_step_compiles_and_fits(chip, monkeypatch):
@@ -213,3 +259,39 @@ def test_sharded_prefill_keeps_reduction_order(topo):
     partial_sums = [ln for ln in txt.splitlines()
                     if "all-reduce(" in ln and "dot_general" in ln]
     assert not partial_sums, partial_sums[0][:300]
+
+
+def test_qwen_decode_step_compiles_and_fits(chip, monkeypatch):
+    """The served decode step at Qwen3-MoE's share and sizes above: QK-
+    norm, GQA 16:1 through both FIER kernels, the held experts' kernel,
+    and bf16 params + the 8 x 24576-token pool + temporaries in one chip."""
+    from repro.serving import Engine
+    from repro.serving.engine import serving_policy
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    pol = dataclasses.replace(
+        serving_policy(budget=QWEN_BUDGET, group=GROUP, skip_layers=2, sink=4, recent=64,
+                       pipeline="one_pass", layout="paged"), block_size=BLOCK)
+    eng = Engine.build(QWEN, n_slots=QWEN_SLOTS, capacity=QWEN_CAPACITY, policy=pol,
+                       layout="paged", block_size=BLOCK)
+    on_chip = lambda tree: jax.tree.map(lambda a: _sds(chip, a.shape, a.dtype), tree)
+    params = on_chip(jax.eval_shape(eng.bundle.init, jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(
+        lambda: eng.bundle.init_cache(QWEN_SLOTS, QWEN_CAPACITY, 0)))
+    # as served: with the step's fresh tail blocks (Engine._open_blocks)
+    compiled = eng._decode_active.lower(
+        params, _sds(chip, (QWEN_SLOTS,), jnp.int32), cache,
+        _sds(chip, (QWEN_SLOTS,), jnp.bool_), _sds(chip, (QWEN_SLOTS,), jnp.int32)).compile()
+    assert _kernel_ops(compiled) == {"paged_fused_retrieve_hm",
+                                     "paged_fused_sparse_attention_hm",
+                                     "held_experts_swiglu"}
+    # the pool is updated in place: no copy of a pool leaf (whose axis 1
+    # is the pool's blocks) to another layout and back
+    n_blocks = jax.tree.leaves(cache["front"])[0].shape[1]
+    pool_copies = re.findall(rf"^\s*(?:ROOT )?%copy[\w.]* = \w+\[\d+,{n_blocks},.*",
+                             compiled.as_text(), re.M)
+    assert not pool_copies, pool_copies[0][:200]
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, mem
