@@ -222,13 +222,14 @@ def _padded_bytes(shape, dtype) -> int:
     return math.prod(major) * rows * lanes * itemsize
 
 
-def _check_vmem(S: int, scratch) -> None:
-    """Trace-time guard: the DMA double buffers, the key row and the rank
-    scratch must fit the scoped VMEM limit."""
+def check_vmem(what: str, scratch) -> None:
+    """Trace-time guard: a kernel's VMEM buffers, ``[(shape, dtype)]``,
+    must fit the scoped VMEM limit; ``what`` names the kernel and the
+    shape that sized them."""
     need = sum(_padded_bytes(sh, dt) for sh, dt in scratch)
     if need > VMEM_LIMIT_BYTES:
         raise ValueError(
-            f"one-pass retrieval at S={S}: its VMEM scratch takes {need} B, "
+            f"{what}: its VMEM scratch takes {need} B, "
             f"over the {VMEM_LIMIT_BYTES} B scoped limit"
         )
 
@@ -338,7 +339,7 @@ def fused_retrieve_hm(
         ((S // blk, blk), jnp.uint32),
         ((1, -(-budget // LANE) * LANE), jnp.int32),
     ]
-    _check_vmem(S, scratch)
+    check_vmem(f"one-pass retrieval at S={S}", scratch)
     idx, tau, m = pl.pallas_call(
         functools.partial(
             _kernel, budget=budget, group=group, blk_s=blk,
@@ -487,7 +488,7 @@ def paged_fused_retrieve_hm(
         ((n_btab, block_size), jnp.uint32),
         ((1, -(-budget // LANE) * LANE), jnp.int32),
     ]
-    _check_vmem(S, scratch)
+    check_vmem(f"one-pass retrieval at S={S}", scratch)
     idx, tau, m = pl.pallas_call(
         functools.partial(
             _paged_kernel, budget=budget, group=group, block_size=block_size,

@@ -35,8 +35,9 @@ from . import topk_select as _tk
 
 
 # selected rows per fused select-and-attend grid step: each row's K and V
-# land in VMEM with all Hkv heads (2·Hkv·D·2 B), so 128 rows keep the
-# scratch and its f32 temporaries near 1 MiB at Hkv = 16, D = 128
+# land in VMEM with all Hkv heads (2·Hkv·D·2 B), in two buffers (this
+# block's and the next's), so 128 rows keep the scratch at 2 MiB and its
+# f32 temporaries near as much at Hkv = 16, D = 128
 ATTEND_BLK = 128
 
 

@@ -85,6 +85,58 @@ def fused_sparse_attention(
     return retrieval.sparse_attention(q, k_sel, v_sel, idx, length)
 
 
+def blockwise_sparse_attention(
+    q: jax.Array,
+    K: jax.Array,
+    V: jax.Array,
+    idx: jax.Array,
+    length: jax.Array | None,
+    blk_k: int,
+) -> jax.Array:
+    """Oracle for the fused attend kernels' arithmetic: the selected rows'
+    online softmax over budget blocks of ``blk_k`` rows, in order, bf16
+    rows scored and summed in f32 with the kernels' expressions.
+
+    q [B,Hq,D], K/V [B,S,Hkv,D], idx [B,Hkv,budget] → f32 [B,Hq,D].  One
+    (batch, kv head) at a time, as the kernels' grid runs them: XLA rounds
+    a batched dot differently."""
+    B, Hq, D = q.shape
+    Hkv, budget = idx.shape[1], idx.shape[2]
+    rep = Hq // Hkv
+    scale = 1.0 / (D**0.5)
+    k_sel, v_sel = retrieval.gather_kv(K, V, idx)          # [B,budget,Hkv,D]
+    valid = jnp.ones(idx.shape, bool) if length is None else idx < length[:, None, None]
+
+    def one_head(a):
+        qh, kh, vh, ok = a                                 # [rep,D], [budget,D] x2, [budget]
+        m = jnp.full((rep,), -jnp.inf, jnp.float32)
+        d = jnp.zeros((rep,), jnp.float32)
+        out = jnp.zeros((rep, D), jnp.float32)
+        for j in range(0, budget, blk_k):
+            k = kh[j:j + blk_k].astype(jnp.float32)
+            v = vh[j:j + blk_k].astype(jnp.float32)
+            okj = ok[None, j:j + blk_k]
+            s = jax.lax.dot_general(
+                qh, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale
+            s = jnp.where(okj, s, retrieval.NEG_INF)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.where(okj, jnp.exp(s - m_new[:, None]), 0.0)
+            out = out * alpha[:, None] + jax.lax.dot(p, v, preferred_element_type=jnp.float32)
+            d = d * alpha + p.sum(axis=-1)
+            m = m_new
+        return out / jnp.maximum(d, 1e-30)[:, None]
+
+    out = jax.lax.map(one_head, (
+        q.astype(jnp.float32).reshape(B * Hkv, rep, D),
+        jnp.swapaxes(k_sel, 1, 2).reshape(B * Hkv, budget, D),
+        jnp.swapaxes(v_sel, 1, 2).reshape(B * Hkv, budget, D),
+        valid.reshape(B * Hkv, budget),
+    ))
+    return out.reshape(B, Hq, D)
+
+
 # --------------------------------------------------- CacheView/plan oracles
 
 def retrieve(

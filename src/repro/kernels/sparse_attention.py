@@ -12,14 +12,16 @@ GPU).  Kept as the fallback and as the shape the jnp oracle mirrors.
 *indices* (int32) plus the full seq-major cache slabs, and pulls each
 selected row HBM→VMEM with per-row async DMA inside the kernel.  No K'/V'
 copy ever exists in HBM: the only cache traffic is budget rows *read*
-directly from the slabs.  The gather loop double-issues the K and V row
-copies so both are in flight per step.  Mosaic DMAs whole (Hkv, D) tiles,
-so each copy brings every kv head of the row and the kernel picks its
-head in VMEM (DESIGN.md §Chip layouts).
+directly from the slabs.  The gather keeps a whole budget block of row
+copies in flight, and the next block's behind it (``_gather_rows``):
+its time was one HBM round trip per two rows, not bytes.  Mosaic DMAs
+whole (Hkv, D) tiles, so each copy brings every kv head of the row and
+the kernel picks its head in VMEM (DESIGN.md §Chip layouts).
 
 Both use the same online-softmax over budget blocks of blk_k rows
-(``ops.ATTEND_BLK`` = 128: with all 16 heads of each row, K + V scratch
-is 1 MiB at D = 128 bf16); larger budgets carry (m, d) across blocks.
+(``ops.ATTEND_BLK`` = 128: with all 16 heads of each row, the fused
+kernels' two K + V buffers take 2 MiB at D = 128 bf16); larger budgets
+carry (m, d) across blocks.
 
 Grids: (B·Hkv, budget/blk_k) unfused; (B, Hkv, budget/blk_k) fused (the
 fused kernel indexes the seq-major [B, S, Hkv, D] slabs directly, so the
@@ -29,13 +31,14 @@ padding when budget > length) arrive as an int8 mask.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .fused_retrieval import head_rows
+from .fused_retrieval import check_vmem, head_rows
 
 NEG_INF = -1e30
 
@@ -135,12 +138,120 @@ def sparse_attention_hm(
 
 # ------------------------------------------------------ fused select+attend
 
-def _idx_blocks(idx: jax.Array, blk_k: int) -> jax.Array:
-    """[B, Hkv, budget] → [B, Hkv, budget/blk_k, 1, blk_k]: one grid step's
-    indices are a whole trailing (1, blk_k) tile, the block shape Mosaic
-    accepts for an SMEM operand."""
-    B, Hkv, budget = idx.shape
-    return idx.reshape(B, Hkv, budget // blk_k, 1, blk_k)
+def _attend_scratch(blk_k, Hkv, D, k_dtype, v_dtype):
+    """The gather's VMEM: two buffers of blk_k all-heads rows each for K
+    and V (``_gather_rows``)."""
+    return [((2, blk_k, Hkv, D), k_dtype), ((2, blk_k, Hkv, D), v_dtype)]
+
+
+def _check_attend_vmem(blk_k, Hkv, D, k_dtype, v_dtype) -> None:
+    """Trace-time guard: the gather's two buffers and the f32 copy of one
+    buffer that ``head_rows`` makes for K and for V fit the scoped VMEM."""
+    f32 = [((blk_k, Hkv, D), jnp.float32)] * 2
+    check_vmem(
+        f"select-and-attend at blk_k={blk_k}, Hkv={Hkv}, D={D}",
+        _attend_scratch(blk_k, Hkv, D, k_dtype, v_dtype) + f32,
+    )
+
+
+# rows a gather loop iteration issues, unrolled: the scalar core pays the
+# loop's own overhead once per 8 rows (69 → 57 ns a row pair on a v5e)
+_ROWS_PER_ITER = 8
+
+
+def _for_each_row(n: int, body) -> None:
+    """``body(i)`` for i in [0, n), in order, gcd(n, ``_ROWS_PER_ITER``)
+    rows unrolled an iteration."""
+    unroll = math.gcd(n, _ROWS_PER_ITER)
+
+    def step(g, carry):
+        for r in range(unroll):
+            body(g * unroll + r)
+        return carry
+
+    jax.lax.fori_loop(0, n // unroll, step, 0)
+
+
+def _gather_rows(row_src, idx_ref, k_vmem, v_vmem, sems):
+    """Bring this grid step's budget block of selected rows into VMEM, with
+    the next block's row copies started behind it; returns the buffer the
+    block is in.  Shared by the slab and the paged kernel.
+
+    row_src(t) → (K row, V row): logical token t's HBM refs, each
+    (1, Hkv, D) — how a row is addressed is all the kernels differ in.
+    idx_ref [1, budget] int32 (SMEM): the (batch, kv head)'s whole index
+    row.  k_vmem/v_vmem [2, blk_k, Hkv, D]: budget block j lands in buffer
+    j % 2, its i-th selected row in row i.  sems [2, 2] DMA: (buffer) ×
+    (K, V).  Every K row copy of a buffer signals one semaphore (V
+    likewise) and is waited for by a descriptor of its own size, so a
+    whole block's copies are in flight at once: the gather pays for
+    issuing two descriptors a row, not for one HBM round trip per two
+    rows.
+    The first block of a (batch, kv head) is started by its own grid
+    step; every step but the last starts block j + 1's copies before it
+    waits for block j, so they land during block j's wait and attention.
+    A wait counts its destination's bytes only, so every wait names one
+    fixed source row and no index is read twice.
+    """
+    j = pl.program_id(2)
+    blk_k = k_vmem.shape[1]
+
+    def start_block(blk):
+        buf = jax.lax.rem(blk, 2)
+
+        def start_row(i):
+            k_src, v_src = row_src(idx_ref[0, blk * blk_k + i])
+            pltpu.make_async_copy(
+                k_src, k_vmem.at[buf, pl.ds(i, 1)], sems.at[buf, 0]
+            ).start()
+            pltpu.make_async_copy(
+                v_src, v_vmem.at[buf, pl.ds(i, 1)], sems.at[buf, 1]
+            ).start()
+
+        _for_each_row(blk_k, start_row)
+
+    @pl.when(j == 0)
+    def _first():
+        start_block(0)
+
+    @pl.when(j + 1 < pl.num_programs(2))
+    def _next():
+        start_block(j + 1)
+
+    buf = jax.lax.rem(j, 2)
+    k_any, v_any = row_src(0)
+
+    def wait_row(i):
+        pltpu.make_async_copy(
+            k_any, k_vmem.at[buf, pl.ds(i, 1)], sems.at[buf, 0]
+        ).wait()
+        pltpu.make_async_copy(
+            v_any, v_vmem.at[buf, pl.ds(i, 1)], sems.at[buf, 1]
+        ).wait()
+
+    _for_each_row(blk_k, wait_row)
+    return buf
+
+
+def _attend_block(row_src, idx_ref, q_ref, mask_ref, out_ref, m_ref, d_ref,
+                  k_vmem, v_vmem, sems, *, scale):
+    """One (batch, kv-head, budget-block) grid step of select-and-attend:
+    gather the block's rows (``_gather_rows``), then one online-softmax
+    update over kv head ``h``'s rows of them."""
+    h = pl.program_id(1)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        d_ref[...] = jnp.zeros_like(d_ref)
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    buf = _gather_rows(row_src, idx_ref, k_vmem, v_vmem, sems)
+    _softmax_accumulate(
+        q_ref[...].astype(jnp.float32), head_rows(k_vmem[buf], h),
+        head_rows(v_vmem[buf], h), mask_ref[...].astype(jnp.int32) > 0,
+        out_ref, m_ref, d_ref, scale=scale,
+    )
 
 
 def _fused_kernel(
@@ -149,67 +260,68 @@ def _fused_kernel(
 ):
     """One (batch, kv-head, budget-block) step of fused select-and-attend.
 
-    idx_ref [1, blk_k] int32 (SMEM); q [rep, D]; mask int8 [1, blk_k];
+    idx_ref [1, budget] int32 (SMEM); q [rep, D]; mask int8 [1, blk_k];
     k_hbm/v_hbm: *whole* seq-major cache slabs [B, S, Hkv, D] (ANY space —
-    never staged through VMEM wholesale); k_vmem/v_vmem [blk_k, Hkv, D]
-    scratch — every kv head of each selected row, since Mosaic DMAs whole
-    (Hkv, D) tiles — from which ``head_rows`` picks head ``h``;
-    sems: [2, 2] DMA semaphores — (slot = row parity) × (K, V) — so the
-    gather loop keeps the next row's copies in flight while waiting on
-    the current row's (double-buffered, not serial round-trips).
+    never staged through VMEM wholesale); token t of batch b is row
+    ``[b, t]``.  k_vmem/v_vmem [2, blk_k, Hkv, D] and sems [2, 2]: the
+    gather's buffers (``_gather_rows``) — every kv head of each selected
+    row, since Mosaic DMAs whole (Hkv, D) tiles — from which
+    ``head_rows`` picks head ``h``.
     """
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    j = pl.program_id(2)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        d_ref[...] = jnp.zeros_like(d_ref)
-        out_ref[...] = jnp.zeros_like(out_ref)
+    def row_src(t):
+        return k_hbm.at[b, pl.ds(t, 1)], v_hbm.at[b, pl.ds(t, 1)]
 
-    blk_k = k_vmem.shape[0]
+    _attend_block(row_src, idx_ref, q_ref, mask_ref, out_ref, m_ref, d_ref,
+                  k_vmem, v_vmem, sems, scale=scale)
 
-    def row_copies(i):
-        """The (K, V) row-i DMA descriptors; slot = i mod 2 double-buffers
-        the semaphores so row i+1's copies are in flight while row i's
-        are being waited on."""
-        row = idx_ref[0, i]
-        slot = jax.lax.rem(i, 2)
-        kcp = pltpu.make_async_copy(
-            k_hbm.at[b, pl.ds(row, 1)], k_vmem.at[pl.ds(i, 1)],
-            sems.at[slot, 0],
-        )
-        vcp = pltpu.make_async_copy(
-            v_hbm.at[b, pl.ds(row, 1)], v_vmem.at[pl.ds(i, 1)],
-            sems.at[slot, 1],
-        )
-        return kcp, vcp
 
-    def start_row(i):
-        kcp, vcp = row_copies(i)
-        kcp.start()
-        vcp.start()
-
-    start_row(0)
-
-    def gather(i, _):
-        @pl.when(i + 1 < blk_k)
-        def _prefetch():
-            start_row(i + 1)
-
-        kcp, vcp = row_copies(i)
-        kcp.wait()
-        vcp.wait()
-        return 0
-
-    jax.lax.fori_loop(0, blk_k, gather, 0)
-
-    _softmax_accumulate(
-        q_ref[...].astype(jnp.float32), head_rows(k_vmem[...], h),
-        head_rows(v_vmem[...], h), mask_ref[...].astype(jnp.int32) > 0,
-        out_ref, m_ref, d_ref, scale=scale,
-    )
+def _attend_call(kernel, q, K, V, idx, mask, *, blk_k, interpret,
+                 lead_specs=(), lead_args=()):
+    """The ``pallas_call`` both fused attend kernels share: grid (B, Hkv,
+    budget/blk_k); the kernel's own leading SMEM operands, then the index
+    row, q, the mask and the two cache operands (ANY space)."""
+    B, Hkv, rep, D = q.shape
+    budget = idx.shape[2]
+    assert budget % blk_k == 0
+    _check_attend_vmem(blk_k, Hkv, D, K.dtype, V.dtype)
+    scale = 1.0 / (D**0.5)
+    out, m, d = pl.pallas_call(
+        functools.partial(kernel, scale=scale),
+        grid=(B, Hkv, budget // blk_k),
+        in_specs=[
+            *lead_specs,
+            # the (batch, kv head)'s whole index row, one SMEM block at
+            # every j: the step of block j reads block j + 1's indices too
+            pl.BlockSpec(
+                (None, None, 1, budget), lambda b, h, j: (b, h, 0, 0),
+                memory_space=pltpu.SMEM,
+            ),
+            pl.BlockSpec((None, None, rep, D), lambda b, h, j: (b, h, 0, 0)),
+            pl.BlockSpec((None, None, 1, blk_k), lambda b, h, j: (b, h, 0, j)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=[
+            pl.BlockSpec((None, None, rep, D), lambda b, h, j: (b, h, 0, 0)),
+            pl.BlockSpec((None, None, rep, 128), lambda b, h, j: (b, h, 0, 0)),
+            pl.BlockSpec((None, None, rep, 128), lambda b, h, j: (b, h, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, Hkv, rep, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, rep, 128), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, rep, 128), jnp.float32),
+        ],
+        scratch_shapes=[
+            *(pltpu.VMEM(sh, dt) for sh, dt in
+              _attend_scratch(blk_k, Hkv, D, K.dtype, V.dtype)),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
+        interpret=interpret,
+    )(*lead_args, idx[:, :, None, :], q, mask, K, V)
+    den = jnp.maximum(d[..., 0], 1e-30)
+    return out / den[..., None]
 
 
 @functools.partial(jax.jit, static_argnames=("blk_k", "interpret"))
@@ -220,7 +332,7 @@ def fused_sparse_attention_hm(
     idx: jax.Array,
     mask: jax.Array,
     *,
-    blk_k: int = 1024,
+    blk_k: int = 128,
     interpret: bool = True,
 ) -> jax.Array:
     """Fused select-and-attend decode attention.
@@ -235,44 +347,10 @@ def fused_sparse_attention_hm(
     written, vs. the unfused path's additional budget·D·2 written + read
     back for each materialised K'/V' copy.
     """
-    B, Hkv, rep, D = q.shape
-    budget = idx.shape[2]
-    blk_k = min(blk_k, budget)
-    assert budget % blk_k == 0
-    grid = (B, Hkv, budget // blk_k)
-    scale = 1.0 / (D**0.5)
-    out, m, d = pl.pallas_call(
-        functools.partial(_fused_kernel, scale=scale),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (None, None, None, 1, blk_k), lambda b, h, j: (b, h, j, 0, 0),
-                memory_space=pltpu.SMEM,
-            ),
-            pl.BlockSpec((None, None, rep, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, 1, blk_k), lambda b, h, j: (b, h, 0, j)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, None, rep, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, rep, 128), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, rep, 128), lambda b, h, j: (b, h, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, rep, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, rep, 128), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, rep, 128), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((blk_k, Hkv, D), K.dtype),
-            pltpu.VMEM((blk_k, Hkv, D), V.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-        interpret=interpret,
-    )(_idx_blocks(idx, blk_k), q, mask, K, V)
-    den = jnp.maximum(d[..., 0], 1e-30)
-    return out / den[..., None]
+    return _attend_call(
+        _fused_kernel, q, K, V, idx, mask,
+        blk_k=min(blk_k, idx.shape[2]), interpret=interpret,
+    )
 
 
 # ------------------------------------------------- paged select+attend
@@ -287,59 +365,18 @@ def _paged_fused_kernel(
     operands are the block-pool slabs [N, bs, Hkv, D] (ANY space) and the
     selected *logical* token index ``t`` is translated in-kernel to
     ``(block_table[t // bs], t % bs)`` via the SMEM-resident table row
-    ``bt_ref [1, n_btab]``.  The online-softmax epilogue is shared, so paged
-    and slab outputs are bit-identical at equal ``blk_k``.
+    ``bt_ref [1, n_btab]``.  The gather and the online-softmax epilogue
+    are shared, so paged and slab outputs are bit-identical at equal
+    ``blk_k``.
     """
-    h = pl.program_id(1)
-    j = pl.program_id(2)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        d_ref[...] = jnp.zeros_like(d_ref)
-        out_ref[...] = jnp.zeros_like(out_ref)
+    def row_src(t):
+        phys = bt_ref[0, t // block_size]
+        off = jax.lax.rem(t, block_size)
+        return k_hbm.at[phys, pl.ds(off, 1)], v_hbm.at[phys, pl.ds(off, 1)]
 
-    blk_k = k_vmem.shape[0]
-
-    def row_copies(i):
-        row = idx_ref[0, i]
-        phys = bt_ref[0, row // block_size]
-        off = jax.lax.rem(row, block_size)
-        slot = jax.lax.rem(i, 2)
-        kcp = pltpu.make_async_copy(
-            k_hbm.at[phys, pl.ds(off, 1)], k_vmem.at[pl.ds(i, 1)],
-            sems.at[slot, 0],
-        )
-        vcp = pltpu.make_async_copy(
-            v_hbm.at[phys, pl.ds(off, 1)], v_vmem.at[pl.ds(i, 1)],
-            sems.at[slot, 1],
-        )
-        return kcp, vcp
-
-    def start_row(i):
-        kcp, vcp = row_copies(i)
-        kcp.start()
-        vcp.start()
-
-    start_row(0)
-
-    def gather(i, _):
-        @pl.when(i + 1 < blk_k)
-        def _prefetch():
-            start_row(i + 1)
-
-        kcp, vcp = row_copies(i)
-        kcp.wait()
-        vcp.wait()
-        return 0
-
-    jax.lax.fori_loop(0, blk_k, gather, 0)
-
-    _softmax_accumulate(
-        q_ref[...].astype(jnp.float32), head_rows(k_vmem[...], h),
-        head_rows(v_vmem[...], h), mask_ref[...].astype(jnp.int32) > 0,
-        out_ref, m_ref, d_ref, scale=scale,
-    )
+    _attend_block(row_src, idx_ref, q_ref, mask_ref, out_ref, m_ref, d_ref,
+                  k_vmem, v_vmem, sems, scale=scale)
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "blk_k", "interpret"))
@@ -352,7 +389,7 @@ def paged_fused_sparse_attention_hm(
     mask: jax.Array,
     *,
     block_size: int,
-    blk_k: int = 1024,
+    blk_k: int = 128,
     interpret: bool = True,
 ) -> jax.Array:
     """Paged fused select-and-attend decode attention.
@@ -364,47 +401,15 @@ def paged_fused_sparse_attention_hm(
     ``budget`` selected rows move HBM→VMEM — no K'/V' copy, and no
     materialised logical-slab view of the pool either.
     """
-    B, Hkv, rep, D = q.shape
-    budget = idx.shape[2]
-    blk_k = min(blk_k, budget)
-    assert budget % blk_k == 0
     assert k_pool.shape[1] == block_size, (k_pool.shape, block_size)
-    grid = (B, Hkv, budget // blk_k)
-    scale = 1.0 / (D**0.5)
     n_btab = block_table.shape[1]
-    out, m, d = pl.pallas_call(
-        functools.partial(_paged_fused_kernel, scale=scale, block_size=block_size),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (None, 1, n_btab), lambda b, h, j: (b, 0, 0),
-                memory_space=pltpu.SMEM,
-            ),
-            pl.BlockSpec(
-                (None, None, None, 1, blk_k), lambda b, h, j: (b, h, j, 0, 0),
-                memory_space=pltpu.SMEM,
-            ),
-            pl.BlockSpec((None, None, rep, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, 1, blk_k), lambda b, h, j: (b, h, 0, j)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, None, rep, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, rep, 128), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, rep, 128), lambda b, h, j: (b, h, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, rep, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, rep, 128), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, rep, 128), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((blk_k, Hkv, D), k_pool.dtype),
-            pltpu.VMEM((blk_k, Hkv, D), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-        interpret=interpret,
-    )(block_table[:, None], _idx_blocks(idx, blk_k), q, mask, k_pool, v_pool)
-    den = jnp.maximum(d[..., 0], 1e-30)
-    return out / den[..., None]
+    return _attend_call(
+        functools.partial(_paged_fused_kernel, block_size=block_size),
+        q, k_pool, v_pool, idx, mask,
+        blk_k=min(blk_k, idx.shape[2]), interpret=interpret,
+        lead_specs=[pl.BlockSpec(
+            (None, 1, n_btab), lambda b, h, j: (b, 0, 0),
+            memory_space=pltpu.SMEM,
+        )],
+        lead_args=[block_table[:, None]],
+    )

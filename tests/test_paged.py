@@ -244,6 +244,76 @@ def test_paged_decode_bit_identical_vs_slab(B, S, Hkv, Hq, D, g, bs):
     )
 
 
+# (B, S, Hkv, Hq, D, bs, budget, blk_k, lengths) for the select-and-attend
+# gather: rep 1 at 16 KV heads and rep 16 at 4 (the two served groups);
+# budgets of several blk_k blocks, so each block's row copies are started
+# by the grid step before it; one single-block budget; slots whose budget
+# exceeds their length (masked padding rows) and whose length ends inside
+# a block (the ragged tail block), with table entries past it on the null
+# block that the padding rows read; a blk_k of 12, which the gather's
+# loops cannot take 8 rows an iteration
+ATTEND_CASES = [
+    (2, 256, 16, 16, 32, 32, 128, 32, (256, 77)),
+    (2, 256, 4, 64, 32, 32, 96, 32, (250, 40)),
+    (1, 128, 16, 16, 128, 16, 64, 64, (50,)),
+    (3, 192, 3, 6, 16, 24, 64, 16, (37, 192, 100)),
+    (2, 96, 2, 4, 16, 16, 36, 12, (90, 30)),
+]
+
+
+def _attend_rows(S, Hkv, budget, bs, lengths, seed):
+    """Ascending distinct positions a (slot, kv head), as retrieval hands
+    them over: every row of the slot's ragged tail block, some rows past
+    its length where it has them, the rest drawn at random."""
+    rng = np.random.default_rng(seed)
+    idx = np.zeros((len(lengths), Hkv, budget), np.int32)
+    for b, L in enumerate(lengths):
+        tail = np.arange(L // bs * bs, L)
+        past = np.arange(L, min(S, L + 5))
+        for h in range(Hkv):
+            rest = np.setdiff1d(np.arange(S), np.r_[tail, past])
+            pick = rng.choice(rest, budget - tail.size - past.size, replace=False)
+            idx[b, h] = np.sort(np.r_[tail, past, pick])
+    return jnp.asarray(idx)
+
+
+@pytest.mark.parametrize("B,S,Hkv,Hq,D,bs,budget,blk,lengths", ATTEND_CASES)
+def test_paged_attend_bit_identical_vs_slab(B, S, Hkv, Hq, D, bs, budget, blk, lengths):
+    """The paged and the slab select-and-attend kernels return the same bits
+    on the same logical rows, and the blockwise oracle's: bit for bit at
+    rep > 1; at rep 1 XLA's CPU backend rounds the one-row dots by their
+    context, so there to 1e-6 (a wrong row or mask moves the output by
+    far more).  The oracle itself matches a float64 softmax."""
+    q, K, V, qk, k_pool, v_pool, meta, table = _paged_inputs(
+        B, S, Hkv, Hq, D, 8, bs, seed=3
+    )
+    length = jnp.asarray(lengths, jnp.int32)
+    used = -(-length // bs)
+    table = jnp.where(jnp.arange(S // bs)[None] < used[:, None], table, 0)
+    idx = _attend_rows(S, Hkv, budget, bs, lengths, seed=4)
+    slab = ops.attend_selected(q, CacheView.slab(K, V, None, length), idx, blk_k=blk)
+    pview = CacheView.paged(k_pool, v_pool, None, table, length)
+    got = ops.attend_selected(q, pview, idx, blk_k=blk)
+    np.testing.assert_array_equal(np.asarray(slab), np.asarray(got))
+
+    want = np.asarray(ref.blockwise_sparse_attention(q, K, V, idx, length, blk))
+    if Hq // Hkv > 1:
+        np.testing.assert_array_equal(np.asarray(got), want)
+    else:
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6, atol=1e-6)
+
+    rows = np.arange(B)[:, None, None]
+    heads = np.arange(Hkv)[None, :, None]
+    ksel = np.asarray(K, np.float64)[rows, np.asarray(idx), heads]   # [B, Hkv, budget, D]
+    vsel = np.asarray(V, np.float64)[rows, np.asarray(idx), heads]
+    q4 = np.asarray(q, np.float64).reshape(B, Hkv, Hq // Hkv, D)
+    s = np.einsum("bhrd,bhkd->bhrk", q4, ksel) / np.sqrt(D)
+    s = np.where((np.asarray(idx) < np.asarray(length)[:, None, None])[:, :, None], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    exact = np.einsum("bhrk,bhkd->bhrd", p / p.sum(-1, keepdims=True), vsel)
+    np.testing.assert_allclose(want, exact.reshape(B, Hq, D), rtol=1e-5, atol=1e-5)
+
+
 def test_paged_append_matches_slab_append():
     """Appending one token through the block table leaves the same logical
     cache (K/V rows and refreshed side-car) as the slab append."""

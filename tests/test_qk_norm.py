@@ -59,9 +59,12 @@ def test_qwen3_configuration_states_the_published_block():
 
 # SHA-256 of the lowered paged decode step and final prefill chunk of a
 # small olmo-1b, taken from the program before QK-norm, the norm epsilon
-# and the expert share existed: with them off, the program is unchanged
+# and the expert share existed: with them off, the program is unchanged.
+# The decode pin was taken again when the select-and-attend gather went a
+# block deep; that program differs from the one before only inside
+# paged_fused_sparse_attention_hm and in the numbering of helper functions
 OLMO_PROGRAMS = {
-    "decode": "0fc99754ca05ac01c0c5bd789b4abf0b70e9b73f748768a7b5d6d1427df75717",
+    "decode": "70a2bbdf3b3d2417ad42a983bc5241f610d5b19ab17f5ffea16dd2d045e72754",
     "chunk": "40684d65dba24ebaf307d4eff4bee0c48884e9c1130a3e651c4cce776e13f9fa",
 }
 

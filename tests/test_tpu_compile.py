@@ -154,6 +154,34 @@ def test_paged_attend_compiles_gqa_16(chip):
                        QWEN_CAPACITY, QWEN_BUDGET) == {"paged_fused_sparse_attention_hm"}
 
 
+@pytest.mark.parametrize("blk_k,fits", [(ops.ATTEND_BLK, True), (1024, False)])
+def test_attend_vmem_guard(blk_k, fits):
+    """The select-and-attend gather's two buffers of all-heads rows are
+    checked against the scoped VMEM limit while tracing: olmo-1b's 16 KV
+    heads at budget 4096 fit at the served block; a block of 1024 rows
+    (32 MiB with its f32 copies) is refused, naming its shape."""
+    slots, capacity, budget = SLOTS, 32768, 4096
+    sds = jax.ShapeDtypeStruct
+    n_blocks = slots * (capacity // BLOCK) + 1
+    args = (
+        sds((slots, HKV, REP, D), jnp.bfloat16),
+        sds((n_blocks, BLOCK, HKV, D), jnp.bfloat16),
+        sds((n_blocks, BLOCK, HKV, D), jnp.bfloat16),
+        sds((slots, capacity // BLOCK), jnp.int32),
+        sds((slots, HKV, budget), jnp.int32),
+        sds((slots, HKV, 1, budget), jnp.int8),
+    )
+
+    def f(*a):
+        return sa.paged_fused_sparse_attention_hm(*a, block_size=BLOCK, blk_k=blk_k)
+
+    if fits:
+        assert jax.eval_shape(f, *args).shape == (slots, HKV, REP, D)
+    else:
+        with pytest.raises(ValueError, match=f"blk_k={blk_k}, Hkv={HKV}, D={D}"):
+            jax.eval_shape(f, *args)
+
+
 @pytest.mark.parametrize("tokens", [QWEN_SLOTS, 2048])
 def test_held_experts_compiles(chip, tokens):
     """A decode step's tokens, and a prefill chunk's, through the held
